@@ -14,6 +14,8 @@ def main(argv=None):
     ap.add_argument("--skip-roofline", action="store_true")
     args = ap.parse_args(argv)
 
+    from repro.kernels import ops
+    ops.enable_compile_cache()
     print("name,us_per_call,derived")
 
     print("# --- Fig 4: single-task DVFS optimum (S5.2) ---", flush=True)

@@ -40,6 +40,7 @@ import numpy as np
 
 from repro.core import online, tasks
 from repro.core.jobs import RooflineTerms, jobs_to_task_set, synth_job_stream
+from repro.kernels import ops
 
 FALLBACK = {
     "qwen2-72b/train_4k": RooflineTerms("qwen2-72b", "train_4k",
@@ -82,6 +83,7 @@ def main():
                          "repro.core.machines registry, e.g. "
                          "gtx-1080ti,tpu-v5e (default: homogeneous)")
     args = ap.parse_args()
+    ops.enable_compile_cache()
     mix = args.classes.split(",") if args.classes else None
 
     terms = load_roofline(args.dryrun_dir)

@@ -10,6 +10,7 @@ import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
+from repro.kernels import ops
 from repro.launch.serve import main as serve_main
 
 
@@ -19,6 +20,7 @@ def main():
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--gen", type=int, default=32)
     args = ap.parse_args()
+    ops.enable_compile_cache()
     serve_main(["--arch", args.arch, "--preset", "smoke",
                 "--requests", str(args.requests), "--gen", str(args.gen)])
 

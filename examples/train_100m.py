@@ -14,6 +14,7 @@ import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
+from repro.kernels import ops
 from repro.launch.train import main as train_main
 
 
@@ -24,6 +25,7 @@ def main():
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=256)
     args = ap.parse_args()
+    ops.enable_compile_cache()
     train_main(["--arch", args.arch, "--preset", "100m",
                 "--steps", str(args.steps), "--batch", str(args.batch),
                 "--seq", str(args.seq), "--lr", "3e-3",

@@ -285,23 +285,35 @@ def configure_classes_async(params: DvfsParams, allowed: np.ndarray,
     from repro.kernels import ops as kernel_ops
     from repro.kernels.dvfs_opt import DEFAULT_GRID
 
-    n = allowed.shape[0]
-    adapted = [mc.adapt(params) for mc in classes]
-    ivs = [mc.effective_interval(interval) for mc in classes]
-    big = DvfsParams(*(np.concatenate([np.asarray(f, np.float64)
-                                       for f in cols])
-                       for cols in zip(*(a.astuple() for a in adapted))))
-    interval_rows = np.concatenate(
-        [np.broadcast_to(np.asarray(iv.bounds(), np.float64),
-                         (n, layout.N_BOUNDS))
-         for iv in ivs], axis=0)
-    keys = solver_cache.build_keys(big.astuple(), np.tile(allowed, len(ivs)),
-                                   False, interval_rows)
+    keys = stacked_keys(params, allowed, classes, interval)
     handle = solver_cache.solve_rows_async(
         keys, lambda km: kernel_ops.dvfs_solve_matrix(km, block=False),
         tag=f"k{int(DEFAULT_GRID[0])}x{int(DEFAULT_GRID[1])}",
         cache=solver_cache.GLOBAL_CACHE if dedup else None, unique=False)
-    return ClassSolves(stacked=handle, n=n)
+    return ClassSolves(stacked=handle, n=allowed.shape[0])
+
+
+def stacked_keys(params: DvfsParams, allowed: np.ndarray,
+                 classes: Sequence[MachineClass],
+                 interval: ScalingInterval = dvfs.WIDE) -> np.ndarray:
+    """The class-stacked ``[C*n, 13]`` key matrix of one kernel dispatch:
+    block ``c`` holds every task adapted to class ``c``, each row carrying
+    that class's interval bounds."""
+    from repro.core import solver_cache
+
+    n = np.shape(allowed)[0]
+    adapted = [mc.adapt(params) for mc in classes]
+    big = DvfsParams(*(np.concatenate([np.asarray(f, np.float64)
+                                       for f in cols])
+                       for cols in zip(*(a.astuple() for a in adapted))))
+    interval_rows = np.concatenate(
+        [np.broadcast_to(
+            np.asarray(mc.effective_interval(interval).bounds(), np.float64),
+            (n, layout.N_BOUNDS))
+         for mc in classes], axis=0)
+    return solver_cache.build_keys(big.astuple(),
+                                   np.tile(allowed, len(classes)), False,
+                                   interval_rows)
 
 
 def default_configs(task_set, classes: Sequence[MachineClass],

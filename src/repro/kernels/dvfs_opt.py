@@ -82,7 +82,20 @@ def _g1_inv(fc):
     return G1_B * jnp.square(jnp.maximum(fc - G1_C, 0.0)) + G1_A
 
 
-def _hier_argmin(efn, rows, g0: int, g1: int):
+def _lanes(k: int):
+    """``[BT, k]`` int32 lane index.  Mosaic's iota is integer-only."""
+    return jax.lax.broadcasted_iota(jnp.int32, (BT, k), 1)
+
+
+def _take(x, onehot):
+    """Per-row ``x[r, i_r]`` as a masked lane sum over a one-hot mask.
+
+    Mosaic has no in-kernel gather; exactly one lane survives the mask and
+    the rest add ``0.0``, so the result is bitwise the gathered value."""
+    return jnp.sum(jnp.where(onehot, x, 0.0), axis=1)
+
+
+def _hier_argmin(efn, g0: int, g1: int):
     """Coarse-then-fine argmin of ``efn`` over the unit interval.
 
     ``efn`` maps a fraction array ``[BT, k]`` to energies ``[BT, k]``.
@@ -91,20 +104,24 @@ def _hier_argmin(efn, rows, g0: int, g1: int):
     returns the per-row winning fraction ``[BT]`` — guarded so the fine
     winner is never worse than the coarse one (refinement is monotone).
     """
-    f0 = jax.lax.broadcasted_iota(jnp.float32, (BT, g0), 1) / (g0 - 1)
+    lane0 = _lanes(g0)
+    f0 = lane0.astype(jnp.float32) / (g0 - 1)
     e0 = efn(f0)
     i0 = jnp.argmin(e0, axis=1)
-    e0_best = e0[rows, i0]
-    f0_best = f0[rows, i0]
+    hot0 = lane0 == i0[:, None]
+    e0_best = _take(e0, hot0)
+    f0_best = _take(f0, hot0)
     step = 1.0 / (g0 - 1)
     f_lo = jnp.clip((i0.astype(jnp.float32) - 1.0) * step, 0.0, 1.0)
     f_hi = jnp.clip((i0.astype(jnp.float32) + 1.0) * step, 0.0, 1.0)
-    frac = jax.lax.broadcasted_iota(jnp.float32, (BT, g1), 1) / (g1 - 1)
+    lane1 = _lanes(g1)
+    frac = lane1.astype(jnp.float32) / (g1 - 1)
     f1 = f_lo[:, None] + (f_hi - f_lo)[:, None] * frac
     e1 = efn(f1)
     i1 = jnp.argmin(e1, axis=1)
-    e1_best = e1[rows, i1]
-    f1_best = f1[rows, i1]
+    hot1 = lane1 == i1[:, None]
+    e1_best = _take(e1, hot1)
+    f1_best = _take(f1, hot1)
     return jnp.where(e1_best <= e0_best, f1_best, f0_best)
 
 
@@ -123,7 +140,6 @@ def _kernel(tasks_ref, out_ref, *, g0: int, g1: int):
     v_min, v_max = t[:, col(V_MIN)], t[:, col(V_MAX)]
     fc_min, fm_min, fm_max = (t[:, col(FC_MIN)], t[:, col(FM_MIN)],
                               t[:, col(FM_MAX)])
-    rows = jnp.arange(BT)
 
     def energy_at(v, fc, fm):
         pw = p0 + gamma * fm + cc * jnp.square(v) * fc
@@ -147,7 +163,7 @@ def _kernel(tasks_ref, out_ref, *, g0: int, g1: int):
         e, _, tt = energy_at(v, fc, fm)
         return e, (v, fc, fm, tt)
 
-    fu = _hier_argmin(lambda f: unc_at(f)[0], rows, g0, g1)
+    fu = _hier_argmin(lambda f: unc_at(f)[0], g0, g1)
     _, (v_1, fc_1, fm_1, t_1) = unc_at(fu[:, None])      # [BT, 1] at winner
     v_u, fc_u, fm_u, t_un = _sq(v_1), _sq(fc_1), _sq(fm_1), _sq(t_1)
 
@@ -166,7 +182,7 @@ def _kernel(tasks_ref, out_ref, *, g0: int, g1: int):
         e = jnp.where(bad | (fc_req > fc_max + 1e-6), INF, e)
         return e, (v2, fc2, fm2)
 
-    fb = _hier_argmin(lambda f: bnd_at(f)[0], rows, g0, g1)
+    fb = _hier_argmin(lambda f: bnd_at(f)[0], g0, g1)
     _, (v_2, fc_2, fm_2) = bnd_at(fb[:, None])
     v_d, fc_d, fm_d = _sq(v_2), _sq(fc_2), _sq(fm_2)
 

@@ -7,6 +7,7 @@ lowering); on a real TPU backend the same calls compile to Mosaic.
 
 from __future__ import annotations
 
+import os
 from typing import Optional
 
 import jax
@@ -33,7 +34,23 @@ def default_interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
-_interpret = default_interpret  # back-compat alias for older call sites
+#: Fixed home of the persistent compilation cache when the environment
+#: names none: the cache key includes the path, so it must not move.
+COMPILE_CACHE_DIR = os.path.normpath(os.path.join(
+    os.path.dirname(os.path.abspath(__file__)), *[os.pardir] * 3, ".jax_cache"))
+
+
+def enable_compile_cache() -> None:
+    """Turn on JAX's persistent compilation cache for an entry point.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, already points JAX at its
+    directory and is left alone; otherwise the cache lives in the
+    checkout's ``.jax_cache``.  The compile-time floor drops to zero so the
+    many small per-shape solve compiles (``solver_cache._pad_rows``) are
+    cached too.  Called by entry points, never at import."""
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir", COMPILE_CACHE_DIR)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
 
 
 def _pad_head_dim(x: jax.Array, to: int = 128) -> jax.Array:
@@ -57,14 +74,14 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     # scale uses the REAL dh: compensate the kernel's padded-dh scale.
     fix = (qp.shape[-1] / dh) ** 0.5
     out = _flash(qp * fix, kp, vp, causal=causal, window=window, bq=bq,
-                 bk=bk, interpret=_interpret())
+                 bk=bk, interpret=default_interpret())
     return out[..., :dh]
 
 
 def ssd_scan(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array,
              c: jax.Array, chunk: int = 128) -> jax.Array:
     """SSD chunked scan (no D-skip).  See kernels/ssd_scan.py."""
-    return _ssd(x, dt, a, b, c, chunk=chunk, interpret=_interpret())
+    return _ssd(x, dt, a, b, c, chunk=chunk, interpret=default_interpret())
 
 
 def dvfs_solve_matrix(mat: np.ndarray, *, grid: tuple = DEFAULT_GRID,
@@ -111,8 +128,7 @@ def dvfs_solve_matrix(mat: np.ndarray, *, grid: tuple = DEFAULT_GRID,
         pad = np.broadcast_to(PAD_ROW, (nd * chunk - m, layout.NCOL))
         mat = np.concatenate([mat, pad], axis=0)
     parts = [dvfs_solve_kernel(
-                 jax.device_put(jnp.asarray(mat[i * chunk:(i + 1) * chunk]),
-                                devs[i]),
+                 jax.device_put(mat[i * chunk:(i + 1) * chunk], devs[i]),
                  grid=grid, interpret=interpret)
              for i in range(nd)]  # dispatches are async; concat blocks
 

@@ -316,8 +316,6 @@ def capture(compiled) -> Dict[str, Any]:
                          + max(0, mem["output_size_in_bytes"]
                                - mem["alias_size_in_bytes"]))
     ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):         # pre-0.5 jax: one dict per device
-        ca = ca[0] if ca else {}
     cost = {"flops": float(ca.get("flops", 0.0)),
             "bytes_accessed": float(ca.get("bytes accessed", 0.0))}
     text = compiled.as_text()

@@ -10,12 +10,7 @@ from __future__ import annotations
 import math
 
 import jax
-from jax.sharding import Mesh
-
-try:  # jax >= 0.5 exposes explicit axis types; older versions have none.
-    from jax.sharding import AxisType
-except ImportError:  # pragma: no cover - depends on installed jax
-    AxisType = None
+from jax.sharding import AxisType, Mesh
 
 
 def _mesh(shape, axes):
@@ -26,10 +21,8 @@ def _mesh(shape, axes):
             f"mesh {shape} needs {n} devices, found {len(devs)} — the "
             "dry-run must set XLA_FLAGS=--xla_force_host_platform_device_"
             "count=512 before importing jax")
-    kwargs = {}
-    if AxisType is not None:
-        kwargs["axis_types"] = (AxisType.Auto,) * len(axes)
-    return jax.make_mesh(shape, axes, devices=devs[:n], **kwargs)
+    return jax.make_mesh(shape, axes, devices=devs[:n],
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:

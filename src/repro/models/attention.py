@@ -30,16 +30,10 @@ from repro.models.layers import COMPUTE_DTYPE, ParamBuilder, Params, apply_rope
 NEG_INF = -1e30
 DEFAULT_CHUNK = 1024
 
-if hasattr(jax, "shard_map"):                      # jax >= 0.6
-    def _shard_map(f, *, mesh, in_specs, out_specs):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-else:                                              # pinned 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
 
-    def _shard_map(f, *, mesh, in_specs, out_specs):
-        return _shard_map_impl(f, mesh=mesh, in_specs=in_specs,
-                               out_specs=out_specs, check_rep=False)
+def _shard_map(f, *, mesh, in_specs, out_specs):
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def init_attention(b: ParamBuilder, cfg: ModelConfig, d_in: Optional[int] = None) -> Params:

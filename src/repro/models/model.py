@@ -40,26 +40,6 @@ from repro.models.layers import (COMPUTE_DTYPE, ParamBuilder, Params,
 CE_CHUNK = 512  # sequence chunk for the checkpointed cross-entropy
 
 
-@jax.custom_jvp
-def _barrier(x):
-    """``optimization_barrier`` with an identity differentiation rule.
-
-    The pinned jax (0.4.x) defines no JVP/transpose for
-    ``optimization_barrier_p``, so putting the raw primitive inside a
-    ``jax.checkpoint``-ed scan body breaks ``jax.grad``.  The barrier is
-    semantically the identity — it only fences XLA scheduling/convert
-    motion — so the tangent passes straight through (and the barrier is
-    NOT applied to the tangent: fencing the primal stash is what matters).
-    """
-    return jax.lax.optimization_barrier(x)
-
-
-@_barrier.defjvp
-def _barrier_jvp(primals, tangents):
-    (x,), (t,) = primals, tangents
-    return jax.lax.optimization_barrier(x), t
-
-
 def _is_axes(x) -> bool:
     return partition.is_axes(x)
 
@@ -374,17 +354,17 @@ class Model:
                                  bidirectional_prefix=prefix, kv_x=kv_x)
         # §Perf H6: barrier keeps the TP partial-sum all-reduce in bf16
         # (the downstream norm's f32 convert otherwise hoists before it).
-        x = x + _barrier(out)
+        x = x + jax.lax.optimization_barrier(out)
         h = _norm(p["ln2"], x, "rms" if self.norm_kind == "rms" else "ln",
                   cfg.norm_eps)
         if cfg.family == "moe":
             y, aux = moe_lib.moe_mlp(p["mlp"], h, cfg)
-            x = x + _barrier(y)
+            x = x + jax.lax.optimization_barrier(y)
             if aux_carry is not None:
                 aux_carry = aux_carry + aux
         else:
-            x = x + _barrier(mlp(p["mlp"], h,
-                                                     cfg.mlp_type))
+            x = x + jax.lax.optimization_barrier(
+                mlp(p["mlp"], h, cfg.mlp_type))
         x = partition.constrain(x, ("batch", "seq", "act_embed"))
         return x, aux_carry
 
@@ -421,7 +401,7 @@ class Model:
             def body(carry, p):
                 # optimization_barrier: stops XLA convert-motion from
                 # stashing the remat carry as f32 (2x stash memory).
-                x, aux = _barrier(carry)
+                x, aux = jax.lax.optimization_barrier(carry)
                 p = self._constrain_layer(p)
                 x, aux = self._attn_mlp_layer(p, x, positions,
                                               window=cfg.sliding_window,
@@ -432,7 +412,7 @@ class Model:
             (x, aux), _ = self._scan(body_fn, (x, aux0), params["layers"])
         elif fam == "ssm":
             def body(x, p):
-                x = _barrier(x)
+                x = jax.lax.optimization_barrier(x)
                 p = self._constrain_layer(p)
                 h = _norm(p["ln"], x, "rms", cfg.norm_eps)
                 x = x + ssm_lib.mamba2_block(p["mixer"], h, cfg)
@@ -445,7 +425,7 @@ class Model:
             pattern = cfg.block_pattern
 
             def unit_body(x, unit):
-                x = _barrier(x)
+                x = jax.lax.optimization_barrier(x)
                 unit = self._constrain_layer(unit)
                 for i, kind in enumerate(pattern):
                     x = self._hybrid_layer(unit[i], x, positions, kind)
@@ -460,7 +440,7 @@ class Model:
             enc = self._encode(params, batch["frames"], remat=remat)
 
             def body(x, p):
-                x = _barrier(x)
+                x = jax.lax.optimization_barrier(x)
                 p = self._constrain_layer(p)
                 x = self._decoder_layer(p, x, positions, enc)
                 return x, None
@@ -484,7 +464,7 @@ class Model:
         x = partition.constrain(x, ("batch", "seq", "act_embed"))
 
         def body(x, p):
-            x = _barrier(x)
+            x = jax.lax.optimization_barrier(x)
             p = self._constrain_layer(p, "enc_layers")
             x, _ = self._attn_mlp_layer(p, x, None, causal=False, rope=False)
             return x, None
@@ -725,7 +705,7 @@ class Model:
             def body(x, layer):
                 # barrier: keeps per-layer weight/cache casts inside the
                 # loop (CPU hoists them into whole-stack f32 copies).
-                p, k, v = _barrier(layer)
+                p, k, v = jax.lax.optimization_barrier(layer)
                 h = _norm(p["ln1"], x[:, None], "rms", cfg.norm_eps)[:, 0]
                 out, k, v = attn_lib.decode_attn(p["attn"], h, cfg, k, v, pos, W)
                 x = x + out
@@ -742,7 +722,7 @@ class Model:
             new_cache = {"k": ks, "v": vs}
         elif fam == "ssm":
             def body(x, layer):
-                p, conv, ssm_st = _barrier(layer)
+                p, conv, ssm_st = jax.lax.optimization_barrier(layer)
                 h = _norm(p["ln"], x[:, None], "rms", cfg.norm_eps)[:, 0]
                 out, (conv, ssm_st) = ssm_lib.mamba2_decode(
                     p["mixer"], h, cfg, (conv, ssm_st))
@@ -771,7 +751,7 @@ class Model:
                 return x, st
 
             def unit_body(x, unit):
-                ps, sts = _barrier(unit)
+                ps, sts = jax.lax.optimization_barrier(unit)
                 new = []
                 for i, kind in enumerate(pattern):
                     x, st = apply_layer(ps[i], x, kind, sts[i])
@@ -789,7 +769,7 @@ class Model:
             W = cache["k"].shape[2]
 
             def body(x, layer):
-                p, k, v, xk, xv = _barrier(layer)
+                p, k, v, xk, xv = jax.lax.optimization_barrier(layer)
                 h = _norm(p["ln1"], x[:, None], "ln", cfg.norm_eps)[:, 0]
                 out, k, v = attn_lib.decode_attn(p["self"], h, cfg, k, v, pos, W)
                 x = x + out

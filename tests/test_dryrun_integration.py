@@ -13,7 +13,7 @@ SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 
 
 def run_py(code: str, devices: int, timeout=600):
-    env = dict(os.environ)
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
     env["PYTHONPATH"] = SRC
     return subprocess.run([sys.executable, "-c", textwrap.dedent(code)],

@@ -206,7 +206,9 @@ def _fuzz_boxes(rng):
         v_min = float(rng.uniform(0.5, 0.9))
         v_max = float(rng.uniform(v_min + 0.05, 1.24))
         fm_min = float(rng.uniform(0.5, 0.9))
-        boxes.append((v_min, v_max, float(rng.uniform(0.5, 0.8)),
+        # A box needs fc_min <= g1(v_max), or no core frequency is in it.
+        fc_min = min(float(rng.uniform(0.5, 0.8)), dvfs.g1_float(v_max))
+        boxes.append((v_min, v_max, fc_min,
                       fm_min, float(rng.uniform(fm_min + 0.05, 1.2))))
     v = float(rng.uniform(0.7, 1.2))
     fc = dvfs.g1_float(v)

@@ -197,8 +197,9 @@ def test_kernel_pad_rows_are_benign():
 
 def test_sharded_dispatch_matches_single_device():
     """dvfs_solve_matrix(shard=True) is bitwise identical to the
-    single-device path — proven on 2 forced host devices in a subprocess
-    (device count is fixed at jax import time)."""
+    single-device path, each part placed straight on its own device —
+    proven on 2 forced host devices in a subprocess (device count is fixed
+    at jax import time)."""
     code = """
 import numpy as np
 from repro.core import dvfs, tasks
@@ -210,13 +211,20 @@ ts = tasks.generate_offline_n(5000, seed=5, library=tasks.app_library())
 keys = build_keys(ts.params.astuple(),
                   np.asarray(ts.deadline - ts.arrival), False,
                   np.asarray(dvfs.WIDE.bounds(), np.float32))
+placed, kernel = [], ops.dvfs_solve_kernel
+def spy(x, **kw):
+    placed.append(x.devices())
+    return kernel(x, **kw)
+ops.dvfs_solve_kernel = spy
 a = ops.dvfs_solve_matrix(keys, shard=True)
+ops.dvfs_solve_kernel = kernel
+assert placed == [{d} for d in jax.local_devices()], placed
 b = ops.dvfs_solve_matrix(keys, shard=False)
 assert a.shape == (5000, 8)
 assert np.array_equal(a, b)
 print("OK")
 """
-    env = dict(os.environ,
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
                XLA_FLAGS="--xla_force_host_platform_device_count=2",
                PYTHONPATH=os.pathsep.join(
                    [os.path.join(os.path.dirname(__file__), "..", "src")]
