@@ -206,9 +206,7 @@ def _fuzz_boxes(rng):
         v_min = float(rng.uniform(0.5, 0.9))
         v_max = float(rng.uniform(v_min + 0.05, 1.24))
         fm_min = float(rng.uniform(0.5, 0.9))
-        # A box needs fc_min <= g1(v_max), or no core frequency is in it.
-        fc_min = min(float(rng.uniform(0.5, 0.8)), dvfs.g1_float(v_max))
-        boxes.append((v_min, v_max, fc_min,
+        boxes.append((v_min, v_max, float(rng.uniform(0.5, 0.8)),
                       fm_min, float(rng.uniform(fm_min + 0.05, 1.2))))
     v = float(rng.uniform(0.7, 1.2))
     fc = dvfs.g1_float(v)
@@ -253,20 +251,40 @@ def check_kernel_matches_oracle_fuzz(seed: int, n: int = 64):
     assert float(np.median(rel)) < 2e-3
     assert float(np.mean(rel)) < 1e-2
     assert float(np.mean((got[:, 6] > .5) == (expect[:, 6] > .5))) >= 0.9
+    assert np.all(np.isfinite(got))
     # solutions stay inside their per-row box
-    for j, (lo_c, hi_c) in ((0, (8, 9)), (2, (11, 12))):   # v, fm
-        assert np.all(got[:, j] >= mat[:, lo_c] - 1e-4)
-        assert np.all(got[:, j] <= mat[:, hi_c] + 1e-4)
-    assert np.all(got[:, 1] >= mat[:, 10] - 1e-4)          # fc >= fc_min
+    fc_top = np.asarray(dvfs.g1(mat[:, 9]))                # g1(v_max)
+    empty = mat[:, 10] > fc_top + 1e-4       # box holds no core frequency
+    box = ~empty
+    assert np.all(got[:, 0] >= mat[:, 8] - 1e-4)           # v >= v_min
+    assert np.all(got[box, 0] <= mat[box, 9] + 1e-4)       # v <= v_max
+    assert np.all(got[:, 2] >= mat[:, 11] - 1e-4)          # fm in its box
+    assert np.all(got[:, 2] <= mat[:, 12] + 1e-4)
+    assert np.all(got[box, 1] >= mat[box, 10] - 1e-4)      # fc >= fc_min
+    # a box with fc_min > g1(v_max) holds no core frequency: the solve
+    # stays on the V-fc curve between g1(v_max) and fc_min
+    fc_e = got[empty, 1]
+    assert np.all(fc_e >= fc_top[empty] - 1e-4)
+    assert np.all(fc_e <= mat[empty, 10] + 1e-4)
+    assert np.allclose(got[empty, 0],
+                       np.maximum(mat[empty, 8], dvfs.g1_inv(fc_e)),
+                       atol=1e-4)
     # feasible deadline-prior rows respect their window (both sides)
     for out in (got, expect):
         ok = (out[:, 7] > .5) & (out[:, 6] > .5)
         assert np.all(out[ok, 3] <= allowed[ok] * (1 + 1e-3))
+    return int(empty.sum())
 
 
 @pytest.mark.parametrize("seed", range(5))
 def test_dvfs_kernel_fuzz_vs_oracle(seed):
     check_kernel_matches_oracle_fuzz(seed)
+
+
+def test_dvfs_kernel_fuzz_empty_box():
+    """A draw whose random box has fc_min > g1(v_max): kernel and oracle
+    both stay finite and on the V-fc curve."""
+    assert check_kernel_matches_oracle_fuzz(1995334097, n=32) > 0
 
 
 if HAVE_HYPOTHESIS:
