@@ -5,6 +5,10 @@ Pallas kernel, XLA:TPU for the jnp solvers) runs here on the CPU host and
 refuses what the chip would refuse.  Nothing executes.  Every compile
 passes ``interpret=False`` explicitly, since ``ops.default_interpret()``
 sees the CPU backend.
+
+Describing the topology loads libtpu, which takes a per-host lockfile: run
+this file in one process (``--dist loadfile`` under xdist) or set
+``ALLOW_MULTIPLE_LIBTPU_LOAD=1``, or the fixture skips.
 """
 
 import dataclasses
