@@ -93,8 +93,7 @@ def run(groups: int = 1, utils=(0.2, 0.4), rhos=(1, 2),
     # The rho x Delta (and seed-group) cells of one (interval, mix) re-solve
     # identical (params, allowed) rows; the process-wide solve cache serves
     # them after the first cell.  Snapshot the lifetime counters so the
-    # hit-rate below is this sweep's own cross-cell reuse
-    # (``schedule_online`` resets the per-run counters at every call).
+    # hit-rate below is this sweep's own cross-cell reuse.
     cache_base = solver_cache.GLOBAL_CACHE.stats()
 
     for iv_name in intervals:

@@ -25,8 +25,7 @@ def _report_cache(side: str, base: Dict, verbose: bool) -> Dict:
     seed shares the same Algorithm-1 rows, so after the first cell the
     process-wide solve cache serves them all (θ only changes the deferred
     readjustment windows).  Counted as the lifetime-counter delta since
-    ``base`` — ``schedule_online`` resets the per-run counters at every
-    call, so those only cover the last cell."""
+    ``base``: the sweep's own reuse, whatever ran before it."""
     now = solver_cache.GLOBAL_CACHE.stats()
     hits = now["hits_total"] - base["hits_total"]
     misses = now["misses_total"] - base["misses_total"]

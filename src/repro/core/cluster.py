@@ -83,10 +83,14 @@ class ScheduleResult:
     #: fault-injection counters (repro.core.faults.FaultInjector.stats);
     #: None for a failure-free run.
     fault_stats: dict = None
-    #: solve-cache counters (solver_cache.GLOBAL_CACHE.stats, reset at the
-    #: start of each ``schedule_online(dedup=True)`` call so the numbers
-    #: are per-run); None when the run bypassed the cache.
+    #: solve-cache counters: ``hits``/``misses``/``evictions``/``hit_rate``
+    #: of this call's scheduling solves (repro.core.obs counters), ``rows``
+    #: and the lifetime ``*_total`` of solver_cache.GLOBAL_CACHE; None when
+    #: the run bypassed the cache.
     cache_stats: dict = None
+    #: this call's counters (repro.core.obs.COUNTERS); None for a call made
+    #: inside another scheduler call, whose record counts it.
+    counters: dict = None
 
     @property
     def e_total(self) -> float:

@@ -47,7 +47,7 @@ from typing import Optional, Sequence
 
 import numpy as np
 
-from repro.core import cluster as cl
+from repro.core import cluster as cl, obs
 
 _EPS = 1e-9
 
@@ -279,15 +279,16 @@ class ClusterEngine:
         ns = self.n_servers
         if not ns:
             return
-        mu_srv = self._mu_srv[: ns]
-        on = self._on[: ns]
-        off = on & (mu_srv + self.rho <= t + _EPS)
-        if off.any():
-            self._on_time[: ns][off] += (mu_srv[off] + self.rho
-                                         - self._on_since[: ns][off])
-            self._on[: ns][off] = False
-            if self.track_offs:
-                self._off_log.extend(np.flatnonzero(off).tolist())
+        with obs.span("engine.settle"):
+            mu_srv = self._mu_srv[: ns]
+            on = self._on[: ns]
+            off = on & (mu_srv + self.rho <= t + _EPS)
+            if off.any():
+                self._on_time[: ns][off] += (mu_srv[off] + self.rho
+                                             - self._on_since[: ns][off])
+                self._on[: ns][off] = False
+                if self.track_offs:
+                    self._off_log.extend(np.flatnonzero(off).tolist())
 
     def drain_offs(self) -> list:
         """Return (and clear) the server ids powered off since the last
@@ -464,6 +465,7 @@ class ClusterEngine:
             e_overhead += mc.delta_on * float(self._turn_ons[:ns][sm].sum())
         return e_idle, e_overhead
 
+    @obs.spanned("engine.finalize")
     def finalize(self):
         """Close the books: returns ``(e_idle, e_overhead, n_servers)``.
 

@@ -42,7 +42,7 @@ from typing import List, Optional, Sequence, Tuple, Union
 
 import numpy as np
 
-from repro.core import cluster as cl, dvfs, single_task
+from repro.core import cluster as cl, dvfs, obs, single_task
 from repro.core.dvfs import DvfsParams, ScalingInterval
 from repro.core.single_task import TaskConfig
 from repro.kernels import layout
@@ -202,27 +202,28 @@ def configure_classes(params: DvfsParams, allowed: np.ndarray,
     :mod:`repro.core.solver_cache`).
     """
     allowed = np.asarray(allowed, dtype=np.float64)
+    with obs.span("solve.keys"):
+        adapted = [mc.adapt(params) for mc in classes]
+    ivs = [mc.effective_interval(interval) for mc in classes]
     if not use_kernel:
-        return [single_task.configure_tasks(
-                    mc.adapt(params), allowed, mc.effective_interval(interval),
-                    use_kernel=False, dedup=dedup)
-                for mc in classes]
+        return [single_task.configure_tasks(a, allowed, iv, use_kernel=False,
+                                            dedup=dedup)
+                for a, iv in zip(adapted, ivs)]
 
     from repro.kernels import ops as kernel_ops
 
     n = allowed.shape[0]
-    adapted = [mc.adapt(params) for mc in classes]
-    ivs = [mc.effective_interval(interval) for mc in classes]
-    big = DvfsParams(*(np.concatenate([np.asarray(f, np.float64)
-                                       for f in cols])
-                       for cols in zip(*(a.astuple() for a in adapted))))
-    allowed_rep = np.tile(allowed, len(classes))
-    interval_rows = np.concatenate(
-        [np.broadcast_to(np.asarray(iv.bounds(), np.float64),
-                         (n, layout.N_BOUNDS))
-         for iv in ivs], axis=0)
-    big, allowed_rep, interval_rows, _ = single_task.pad_pow2(
-        big, allowed_rep, interval_rows)
+    with obs.span("solve.keys"):
+        big = DvfsParams(*(np.concatenate([np.asarray(f, np.float64)
+                                           for f in cols])
+                           for cols in zip(*(a.astuple() for a in adapted))))
+        allowed_rep = np.tile(allowed, len(classes))
+        interval_rows = np.concatenate(
+            [np.broadcast_to(np.asarray(iv.bounds(), np.float64),
+                             (n, layout.N_BOUNDS))
+             for iv in ivs], axis=0)
+        big, allowed_rep, interval_rows, _ = single_task.pad_pow2(
+            big, allowed_rep, interval_rows)
     sol = kernel_ops.dvfs_solve(big, allowed_rep, interval,
                                 interval_rows=interval_rows, dedup=dedup)
     cfgs: List[TaskConfig] = []
@@ -275,11 +276,13 @@ def configure_classes_async(params: DvfsParams, allowed: np.ndarray,
     """
     allowed = np.asarray(allowed, dtype=np.float64)
     if not use_kernel:
+        with obs.span("solve.keys"):
+            adapted = [mc.adapt(params) for mc in classes]
         return ClassSolves(handles=[
             single_task.solve_rows_async(
-                mc.adapt(params), allowed, mc.effective_interval(interval),
+                a, allowed, mc.effective_interval(interval),
                 boundary=False, use_kernel=False, dedup=dedup)
-            for mc in classes])
+            for a, mc in zip(adapted, classes)])
 
     from repro.core import solver_cache
     from repro.kernels import ops as kernel_ops
@@ -301,19 +304,20 @@ def stacked_keys(params: DvfsParams, allowed: np.ndarray,
     that class's interval bounds."""
     from repro.core import solver_cache
 
-    n = np.shape(allowed)[0]
-    adapted = [mc.adapt(params) for mc in classes]
-    big = DvfsParams(*(np.concatenate([np.asarray(f, np.float64)
-                                       for f in cols])
-                       for cols in zip(*(a.astuple() for a in adapted))))
-    interval_rows = np.concatenate(
-        [np.broadcast_to(
-            np.asarray(mc.effective_interval(interval).bounds(), np.float64),
-            (n, layout.N_BOUNDS))
-         for mc in classes], axis=0)
-    return solver_cache.build_keys(big.astuple(),
-                                   np.tile(allowed, len(classes)), False,
-                                   interval_rows)
+    with obs.span("solve.keys"):
+        n = np.shape(allowed)[0]
+        adapted = [mc.adapt(params) for mc in classes]
+        big = DvfsParams(*(np.concatenate([np.asarray(f, np.float64)
+                                           for f in cols])
+                           for cols in zip(*(a.astuple() for a in adapted))))
+        interval_rows = np.concatenate(
+            [np.broadcast_to(np.asarray(
+                mc.effective_interval(interval).bounds(), np.float64),
+                (n, layout.N_BOUNDS))
+             for mc in classes], axis=0)
+        return solver_cache.build_keys(big.astuple(),
+                                       np.tile(allowed, len(classes)), False,
+                                       interval_rows)
 
 
 def default_configs(task_set, classes: Sequence[MachineClass],
@@ -334,10 +338,11 @@ def class_order(cfgs: Sequence[TaskConfig]) -> np.ndarray:
     """Per-task class preference, shape ``[C, n]``: feasible classes in
     ascending optimized energy first, then infeasible ones by energy.
     ``class_order(cfgs)[0]`` is each task's *primary* class."""
-    e = np.stack([np.asarray(c.e_hat, np.float64) for c in cfgs])
-    feas = np.stack([np.asarray(c.feasible, bool) for c in cfgs])
-    key = np.where(feas, e, e + INFEASIBLE_PENALTY)
-    return np.argsort(key, axis=0, kind="stable")
+    with obs.span("solve.config"):
+        e = np.stack([np.asarray(c.e_hat, np.float64) for c in cfgs])
+        feas = np.stack([np.asarray(c.feasible, bool) for c in cfgs])
+        key = np.where(feas, e, e + INFEASIBLE_PENALTY)
+        return np.argsort(key, axis=0, kind="stable")
 
 
 def readjust_classes(params: DvfsParams, rows: np.ndarray, windows: np.ndarray,
@@ -354,7 +359,8 @@ def readjust_classes(params: DvfsParams, rows: np.ndarray, windows: np.ndarray,
     for cid in np.unique(class_ids):
         mc = classes[int(cid)]
         m = class_ids == cid
-        sub = mc.adapt(params[rows[m]])
+        with obs.span("solve.keys"):
+            sub = mc.adapt(params[rows[m]])
         out = single_task.readjust_batch(sub, windows[m],
                                          mc.effective_interval(interval),
                                          use_kernel=use_kernel, dedup=dedup)

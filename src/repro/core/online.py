@@ -86,7 +86,8 @@ from typing import List, Optional
 
 import numpy as np
 
-from repro.core import bounds, cluster as cl, dvfs, machines, single_task
+from repro.core import (bounds, cluster as cl, dvfs, machines, obs,
+                        single_task, solver_cache)
 from repro.core.dvfs import ScalingInterval
 from repro.core.engine import ClusterEngine
 from repro.core.faults import FaultInjector, FaultTrace, make_degrade
@@ -197,7 +198,8 @@ class _PipelineState:
         # (astype(copy=False) is a no-op view on the float64 input).
         self.allowed = allowed.astype(np.float64, copy=False)
         n = self.allowed.shape[0]
-        self.adapted = [mc.adapt(self.params) for mc in mcs]
+        with obs.span("solve.keys"):
+            self.adapted = [mc.adapt(self.params) for mc in mcs]
         self.ivs = [mc.effective_interval(interval) for mc in mcs]
         self.tmin = self._floors_sync()
         # Full-horizon config columns, filled chunk by chunk.  f64 storage:
@@ -215,8 +217,11 @@ class _PipelineState:
     def _floors_sync(self) -> list:
         """Whole-horizon ``t_min`` per class, one blocking solve at setup
         (before anything is in flight)."""
-        return [np.asarray(dvfs.min_time(a, iv), np.float64)
-                for a, iv in zip(self.adapted, self.ivs)]
+        with obs.span("solve.config"):
+            floors = [dvfs.min_time(a, iv)
+                      for a, iv in zip(self.adapted, self.ivs)]
+            with obs.span("solve.wait"):
+                return [np.asarray(f, np.float64) for f in floors]
 
     def dispatch(self, idx: np.ndarray):
         """Send one chunk's all-classes solve; returns the in-flight handle
@@ -226,13 +231,12 @@ class _PipelineState:
             self.params[idx], self.allowed[idx], self.mcs, self.interval,
             use_kernel=self.use_kernel, dedup=self.dedup)
 
+    @obs.spanned("solve.config")
     def consume_sync(self, handle, idx: np.ndarray):
         """Block on one chunk's rows and scatter the assembled configs into
         the horizon arrays (+ the chunk's class-preference columns —
         ``argsort(axis=0)`` is per-column independent, so chunk columns
         equal the monolithic ``machines.class_order`` sliced)."""
-        from repro.core import solver_cache
-
         allowed = self.allowed[idx]
         for c, rows in enumerate(handle.result()):
             sol = solver_cache.rows_to_solution(rows)
@@ -298,12 +302,15 @@ class _ReadjustPrefetch:
         for cid in np.unique(cids):
             mc = self.mcs[int(cid)]
             m = cids == cid
+            with obs.span("solve.keys"):
+                sub = mc.adapt(self.params[rows[m]])
             handle = single_task.solve_rows_async(
-                mc.adapt(self.params[rows[m]]), windows[m],
+                sub, windows[m],
                 mc.effective_interval(self.interval), boundary=True,
                 use_kernel=self.use_kernel, dedup=self.dedup)
             self.batches.append((ai[m], windows[m], handle))
 
+    @obs.spanned("schedule.records")
     def flush_sync(self, assignments: List[cl.Assignment],
                    pending: List[PendingRow]):
         """Dispatch the tail rows, block on every batch and write the DVFS
@@ -371,6 +378,7 @@ def _drive_pipelined(groups, state: Optional[_PipelineState],
                 place_group(slot, idx)
 
 
+@obs.call("schedule.online")
 def schedule_online(task_set: TaskSet, l: int = 1, theta: float = 1.0,
                     algorithm: str = "edl", use_dvfs: bool = True,
                     interval: ScalingInterval = dvfs.WIDE,
@@ -420,13 +428,8 @@ def schedule_online(task_set: TaskSet, l: int = 1, theta: float = 1.0,
     mcs = machines.resolve_classes(classes, p_idle=p_idle, delta_on=delta_on)
 
     n = len(task_set)
+    obs.count("tasks", n)
     deadline = np.asarray(task_set.deadline, dtype=np.float64)
-
-    from repro.core import solver_cache
-    if dedup:
-        # Per-run counters (reported as ``result.cache_stats``); the cached
-        # rows themselves persist across runs.
-        solver_cache.GLOBAL_CACHE.reset_stats()
 
     groups = _slot_groups(task_set)
 
@@ -473,17 +476,18 @@ def schedule_online(task_set: TaskSet, l: int = 1, theta: float = 1.0,
             else np.argsort(deadline[idx], kind="stable")
 
         base = len(assignments)
-        if algorithm == "bin" and slot == 0:
-            # Algorithm 6 offline phase: worst-fit on task utilization.
-            ctx.binpack_offline_util(idx, order, t_now)
-        elif placement == "vector":
-            if algorithm == "bin":
-                ctx.place_group_select(idx, order, t_now, "ff")
+        with obs.span("placement.group"):
+            if algorithm == "bin" and slot == 0:
+                # Algorithm 6 offline phase: worst-fit on task utilization.
+                ctx.binpack_offline_util(idx, order, t_now)
+            elif placement == "vector":
+                if algorithm == "bin":
+                    ctx.place_group_select(idx, order, t_now, "ff")
+                else:
+                    ctx.place_group_vector(idx, order, t_now, prep=prep)
             else:
-                ctx.place_group_vector(idx, order, t_now, prep=prep)
-        else:
-            ctx.place_group_scalar(idx, order, t_now,
-                                   "wf" if algorithm == "edl" else "ff")
+                ctx.place_group_scalar(idx, order, t_now,
+                                       "wf" if algorithm == "edl" else "ff")
         if injector is not None:
             injector.register(base)
 
@@ -507,24 +511,25 @@ def schedule_online(task_set: TaskSet, l: int = 1, theta: float = 1.0,
     if injector is not None:
         injector.finalize_records()    # re-price truncated records
 
-    # Per-run solve-cache counters: the config + readjustment solves (the
+    # Per-call solve-cache counters: the config + readjustment solves (the
     # e_bound solve below is not part of the scheduling hot path).
-    cache_stats = solver_cache.GLOBAL_CACHE.stats() if dedup else None
+    cache_stats = solver_cache.GLOBAL_CACHE.call_stats() if dedup else None
 
-    e_idle, e_overhead, n_servers = eng.finalize()
-    e_run = float(sum(a.energy for a in assignments))
-    violations = count_violations(
-        assignments, deadline, chosen_feasibility(cfgs, assignments, n))
-    mk = max((a.finish for a in assignments), default=0.0)
-    e_bound = bounds.theoretical_bound(
-        task_set, interval=interval, classes=mcs, l=l,
-        rho=rho, dedup=dedup).e_bound if bound else 0.0
-    return cl.ScheduleResult(
-        algorithm=f"online-{algorithm}{'+dvfs' if use_dvfs else ''}",
-        e_run=e_run, e_idle=e_idle, e_overhead=e_overhead,
-        n_pairs=eng.n_pairs, n_servers=n_servers,
-        violations=violations, assignments=assignments, makespan=mk,
-        feasible_pairs=eng.feasible_pairs, e_bound=e_bound,
-        fault_stats=dict(injector.stats) if injector is not None else None,
-        cache_stats=cache_stats,
-    )
+    with obs.span("schedule.account"):
+        e_idle, e_overhead, n_servers = eng.finalize()
+        e_run = float(sum(a.energy for a in assignments))
+        violations = count_violations(
+            assignments, deadline, chosen_feasibility(cfgs, assignments, n))
+        mk = max((a.finish for a in assignments), default=0.0)
+        e_bound = bounds.theoretical_bound(
+            task_set, interval=interval, classes=mcs, l=l,
+            rho=rho, dedup=dedup).e_bound if bound else 0.0
+        return cl.ScheduleResult(
+            algorithm=f"online-{algorithm}{'+dvfs' if use_dvfs else ''}",
+            e_run=e_run, e_idle=e_idle, e_overhead=e_overhead,
+            n_pairs=eng.n_pairs, n_servers=n_servers,
+            violations=violations, assignments=assignments, makespan=mk,
+            feasible_pairs=eng.feasible_pairs, e_bound=e_bound,
+            fault_stats=dict(injector.stats) if injector is not None else None,
+            cache_stats=cache_stats,
+        )

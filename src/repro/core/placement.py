@@ -64,7 +64,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from repro.core import cluster as cl, machines
+from repro.core import cluster as cl, machines, obs
 from repro.core.engine import ClusterEngine
 from repro.core.single_task import TaskConfig
 
@@ -498,9 +498,11 @@ class PlacementContext:
         """The :func:`precompute` column lookups, built on first use (the
         scalar reference path never touches them)."""
         if self._pre is None:
-            self._pre = precompute(self.cfgs, self.order_cls)
+            with obs.span("placement.prepare"):
+                self._pre = precompute(self.cfgs, self.order_cls)
         return self._pre
 
+    @obs.spanned("placement.prepare")
     def update_tasks(self, idx):
         """Refresh the :attr:`pre` lookups for the tasks in ``idx`` (an
         index array, or a contiguous ``slice`` — what the pipelined driver
@@ -608,6 +610,7 @@ class PlacementContext:
 
     # -- placement paths -----------------------------------------------------
 
+    @obs.spanned("placement.pin")
     def pin_fresh(self, tids: np.ndarray):
         """Each task on its OWN fresh pair of its primary class at ``t = 0``
         (the offline deadline-prior phase: these tasks must start
@@ -616,6 +619,7 @@ class PlacementContext:
         k = tids.shape[0]
         if k == 0:
             return
+        obs.count("placement.pinned", k)
         cls = self.primary[tids].astype(np.int64, copy=True)
         t_hat = np.empty(k)
         for c in np.unique(cls):
@@ -628,6 +632,7 @@ class PlacementContext:
         self.eng.sync_mu(pids, t_hat)
         self._gather(tids, pids, starts, t_hat, np.zeros(k, dtype=bool), cls)
 
+    @obs.spanned("placement.prepare")
     def prepare_chunk(self, groups):
         """Hoist the per-group prologue of :meth:`place_group_vector` for a
         run of arrival groups (the pipelined driver's chunk): ONE stable
@@ -1039,9 +1044,11 @@ class PlacementContext:
         finish = finish_offline if (grain == 1 and not self.eng.server_mode
                                     and order_cols is None) else finish_scalar
         i = 0
+        batched = 0
         while i < k:
             consumed = batch_round(i)
             i += consumed
+            batched += consumed
             if i >= k:
                 break
             place_one(i)
@@ -1050,6 +1057,8 @@ class PlacementContext:
                 if i < k:
                     finish(i)
                 break
+        obs.count("placement.batched", batched)
+        obs.count("placement.scalar", k - batched)
 
         self._commit_group(gidx, pid_col, start_col, dur_col, readj_col,
                            cls_col)
@@ -1069,6 +1078,7 @@ class PlacementContext:
         k = order.shape[0]
         if k == 0:
             return
+        obs.count("placement.scalar", k)
         pre = self.pre
         gidx = np.asarray(idx)[order]
         gl = gidx.tolist()
@@ -1136,6 +1146,7 @@ class PlacementContext:
         readjust_on = self.readjust
         assignments = self.assignments
         pending = self.pending
+        obs.count("placement.scalar", len(order))
         for r in order:
             gidx = int(idx[int(r)])
             d = deadline[gidx]
@@ -1189,6 +1200,7 @@ class PlacementContext:
                 assignments.append(make_assignment(gidx, pid, start, cfg_c,
                                                    class_id=c))
 
+    @obs.spanned("placement.group")
     def place_orphans(self, tids: np.ndarray, t_now: float, rule: str,
                       degrade=None) -> Tuple[int, int]:
         """Deadline-aware re-placement of tasks orphaned by a pair failure
@@ -1220,6 +1232,7 @@ class PlacementContext:
         tids = np.asarray(tids, dtype=np.int64)
         if tids.size == 0:
             return 0, 0
+        obs.count("placement.scalar", tids.size)
         eng = self.eng
         cfgs = self.cfgs
         deadline = self.deadline
@@ -1306,6 +1319,7 @@ class PlacementContext:
         cfgs = self.cfgs
         deadline = self.deadline
         util = np.zeros(0)
+        obs.count("placement.scalar", len(order))
 
         def grow():
             nonlocal util

@@ -58,7 +58,8 @@ from typing import List, Optional, Sequence
 
 import numpy as np
 
-from repro.core import bounds, cluster as cl, dvfs, machines, single_task
+from repro.core import (bounds, cluster as cl, dvfs, machines, obs,
+                        single_task, solver_cache)
 from repro.core.dvfs import ScalingInterval
 from repro.core.engine import ClusterEngine
 from repro.core.machines import MachineClass, resolve_classes
@@ -101,6 +102,7 @@ def configure_all(task_set: TaskSet, use_dvfs: bool,
                                       dedup=dedup)
 
 
+@obs.spanned("schedule.records")
 def fill_readjusted(assignments: List[cl.Assignment],
                     pending: List[PendingRow],
                     task_set: TaskSet, interval: ScalingInterval,
@@ -169,6 +171,7 @@ def chosen_feasibility(cfgs: Sequence[TaskConfig],
     return feas
 
 
+@obs.call("schedule.offline")
 def schedule_offline(task_set: TaskSet, l: int = 1, theta: float = 1.0,
                      algorithm: str = "edl", use_dvfs: bool = True,
                      interval: ScalingInterval = dvfs.WIDE,
@@ -212,6 +215,7 @@ def schedule_offline(task_set: TaskSet, l: int = 1, theta: float = 1.0,
         raise ValueError("cfgs= needs one TaskConfig per machine class")
 
     n = len(task_set)
+    obs.count("tasks", n)
     deadline = np.asarray(task_set.deadline, dtype=np.float64)
     order_cls = machines.class_order(cfgs)          # [C, n]
     primary = order_cls[0]
@@ -233,6 +237,7 @@ def schedule_offline(task_set: TaskSet, l: int = 1, theta: float = 1.0,
     if placement == "vector":
         ctx.pin_fresh(dp_order)
     else:
+        obs.count("placement.pinned", dp_order.size)
         for t_idx in dp_order:
             t_idx = int(t_idx)
             c = int(primary[t_idx])
@@ -255,31 +260,36 @@ def schedule_offline(task_set: TaskSet, l: int = 1, theta: float = 1.0,
 
     rule = OFFLINE_RULES[algorithm]
     pos = np.arange(order.shape[0])
-    if placement == "vector":
-        if rule == "wf":
-            ctx.place_group_vector(order, pos, 0.0)
+    with obs.span("placement.group"):
+        if placement == "vector":
+            if rule == "wf":
+                ctx.place_group_vector(order, pos, 0.0)
+            else:
+                ctx.place_group_select(order, pos, 0.0, rule)
         else:
-            ctx.place_group_select(order, pos, 0.0, rule)
-    else:
-        ctx.place_group_scalar(order, pos, 0.0, rule)
+            ctx.place_group_scalar(order, pos, 0.0, rule)
 
     # --- Deferred theta-readjustment solves: one batched dispatch per class.
     fill_readjusted(assignments, pending, task_set, interval, use_kernel, mcs,
                     dedup=dedup)
+    # Solve-cache counters of the scheduling solves (not the e_bound solve).
+    cache_stats = solver_cache.GLOBAL_CACHE.call_stats() if dedup else None
 
     # --- Phase 3: Algorithm 3 server grouping + Eq. (6) energies per class.
-    e_run = float(sum(a.energy for a in assignments))
-    e_idle, e_overhead, n_servers = eng.finalize()
-    violations = count_violations(
-        assignments, deadline, chosen_feasibility(cfgs, assignments, n))
-    e_bound = bounds.theoretical_bound(
-        task_set, interval=interval, classes=mcs,
-        dedup=dedup).e_bound if bound else 0.0
-    return cl.ScheduleResult(
-        algorithm=f"{algorithm}{'+dvfs' if use_dvfs else ''}",
-        e_run=e_run, e_idle=e_idle, e_overhead=e_overhead,
-        n_pairs=eng.n_pairs, n_servers=n_servers, violations=violations,
-        assignments=assignments,
-        makespan=float(eng.mu.max()) if eng.n_pairs else 0.0,
-        feasible_pairs=eng.feasible_pairs, e_bound=e_bound,
-    )
+    with obs.span("schedule.account"):
+        e_run = float(sum(a.energy for a in assignments))
+        e_idle, e_overhead, n_servers = eng.finalize()
+        violations = count_violations(
+            assignments, deadline, chosen_feasibility(cfgs, assignments, n))
+        e_bound = bounds.theoretical_bound(
+            task_set, interval=interval, classes=mcs,
+            dedup=dedup).e_bound if bound else 0.0
+        return cl.ScheduleResult(
+            algorithm=f"{algorithm}{'+dvfs' if use_dvfs else ''}",
+            e_run=e_run, e_idle=e_idle, e_overhead=e_overhead,
+            n_pairs=eng.n_pairs, n_servers=n_servers, violations=violations,
+            assignments=assignments,
+            makespan=float(eng.mu.max()) if eng.n_pairs else 0.0,
+            feasible_pairs=eng.feasible_pairs, e_bound=e_bound,
+            cache_stats=cache_stats,
+        )

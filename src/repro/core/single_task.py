@@ -35,7 +35,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import dvfs
+from repro.core import dvfs, obs
 from repro.core.dvfs import DvfsParams, ScalingInterval
 from repro.kernels import layout
 from repro.kernels.layout import DvfsSolution  # noqa: F401  (re-export)
@@ -271,20 +271,23 @@ def config_from_solution(sol: DvfsSolution, params: DvfsParams, allowed,
     the caller already holds it — the pipelined online path computes the
     whole horizon's floors once up front and passes per-chunk slices
     (``min_time`` is elementwise, so slices are bitwise equal)."""
-    sol = DvfsSolution(*(np.asarray(f) for f in sol))
-    if tmin is None:
-        tmin = np.asarray(dvfs.min_time(params, interval))
-    allowed_arr = np.broadcast_to(np.asarray(allowed, np.float64),
-                                  sol.time.shape)
-    t_hat = np.where(sol.deadline_prior & sol.feasible,
-                     np.minimum(sol.time, allowed_arr), sol.time)
-    return TaskConfig(
-        v=sol.v, fc=sol.fc, fm=sol.fm,
-        t_hat=t_hat, p_hat=sol.power, e_hat=sol.power * t_hat,
-        t_min=np.broadcast_to(tmin, sol.time.shape).copy(),
-        deadline_prior=sol.deadline_prior, feasible=sol.feasible,
-        n_deadline_prior=int(np.sum(sol.deadline_prior)),
-    )
+    with obs.span("solve.config"):
+        sol = DvfsSolution(*(np.asarray(f) for f in sol))
+        if tmin is None:
+            floors = dvfs.min_time(params, interval)
+            with obs.span("solve.wait"):
+                tmin = np.asarray(floors)
+        allowed_arr = np.broadcast_to(np.asarray(allowed, np.float64),
+                                      sol.time.shape)
+        t_hat = np.where(sol.deadline_prior & sol.feasible,
+                         np.minimum(sol.time, allowed_arr), sol.time)
+        return TaskConfig(
+            v=sol.v, fc=sol.fc, fm=sol.fm,
+            t_hat=t_hat, p_hat=sol.power, e_hat=sol.power * t_hat,
+            t_min=np.broadcast_to(tmin, sol.time.shape).copy(),
+            deadline_prior=sol.deadline_prior, feasible=sol.feasible,
+            n_deadline_prior=int(np.sum(sol.deadline_prior)),
+        )
 
 
 def no_dvfs_config(params: DvfsParams, allowed) -> TaskConfig:
@@ -342,9 +345,10 @@ def _dedup_solve(params: DvfsParams, allowed, interval: ScalingInterval,
     """
     from repro.core import solver_cache
 
-    keys = solver_cache.build_keys(
-        params.astuple(), allowed, boundary,
-        np.asarray(interval.bounds(), np.float32))
+    with obs.span("solve.keys"):
+        keys = solver_cache.build_keys(
+            params.astuple(), allowed, boundary,
+            np.asarray(interval.bounds(), np.float32))
     solver = solve_on_boundary if boundary else solve_with_deadline
 
     def solve(km: np.ndarray) -> np.ndarray:
@@ -378,9 +382,10 @@ def solve_rows_async(params: DvfsParams, allowed,
     """
     from repro.core import solver_cache
 
-    keys = solver_cache.build_keys(
-        params.astuple(), allowed, boundary,
-        np.asarray(interval.bounds(), np.float32))
+    with obs.span("solve.keys"):
+        keys = solver_cache.build_keys(
+            params.astuple(), allowed, boundary,
+            np.asarray(interval.bounds(), np.float32))
     cache = solver_cache.GLOBAL_CACHE if dedup else None
     if use_kernel:
         from repro.kernels import ops as kernel_ops
@@ -417,7 +422,8 @@ def configure_tasks(params: DvfsParams, allowed, interval: ScalingInterval = dvf
     and serves repeats — within this call or from any previous one — out of
     the process-wide solve cache, bit-identically.
     """
-    params, allowed, _, n = pad_pow2(params, allowed)
+    with obs.span("solve.keys"):
+        params, allowed, _, n = pad_pow2(params, allowed)
     if use_kernel:
         from repro.kernels import ops as kernel_ops
 
@@ -447,7 +453,8 @@ def readjust_batch(params: DvfsParams, windows, interval: ScalingInterval = dvfs
     (so scheduler mu updates land exactly on the deadline).
     """
     windows = np.asarray(windows, dtype=np.float64)
-    params, padded, _, n = pad_pow2(params, windows)
+    with obs.span("solve.keys"):
+        params, padded, _, n = pad_pow2(params, windows)
     if use_kernel:
         from repro.kernels import ops as kernel_ops
 
@@ -457,11 +464,12 @@ def readjust_batch(params: DvfsParams, windows, interval: ScalingInterval = dvfs
         sol = _dedup_solve(params, padded, interval, boundary=True)
     else:
         sol = solve_on_boundary(params, padded, interval)
-    v, fc, fm, t, p = (np.asarray(f, np.float64)[:n]
-                       for f in (sol.v, sol.fc, sol.fm, sol.time, sol.power))
-    feas = np.asarray(sol.feasible)[:n]
-    t = np.where(feas, np.minimum(t, windows), t)  # snap the f32 residual
-    return v, fc, fm, t, p, p * t
+    with obs.span("solve.config"):
+        v, fc, fm, t, p = (np.asarray(f, np.float64)[:n] for f in (
+            sol.v, sol.fc, sol.fm, sol.time, sol.power))
+        feas = np.asarray(sol.feasible)[:n]
+        t = np.where(feas, np.minimum(t, windows), t)  # snap f32 residual
+        return v, fc, fm, t, p, p * t
 
 
 def readjust(params: DvfsParams, new_allowed: float,

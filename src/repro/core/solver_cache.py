@@ -45,6 +45,7 @@ from typing import Callable, Optional, Sequence
 
 import numpy as np
 
+from repro.core import obs
 from repro.kernels import layout
 from repro.kernels.layout import DvfsSolution, KEY_COLS, SOL_COLS
 
@@ -63,8 +64,8 @@ class SolveCache:
 
     Sized in *rows*; the default :data:`GLOBAL_CACHE` keeps 2^18 rows
     (~25 MB of keys+values), far above any single sweep's working set.
-    ``hits``/``misses`` accumulate across calls — sweep benchmarks report
-    them as the cross-cell reuse rate.
+    ``hits``/``misses`` accumulate across calls until :meth:`reset_stats`;
+    each call also counts its own in :mod:`repro.core.obs`.
     """
 
     def __init__(self, maxsize: int = 1 << 18):
@@ -76,9 +77,9 @@ class SolveCache:
         self.misses = 0
         self.evictions = 0
         # Lifetime counters: same increments, never cleared by
-        # ``reset_stats`` — ``schedule_online`` resets the per-run counters
-        # at every call, so cross-run consumers (sweep benchmarks) diff
-        # these instead.
+        # ``reset_stats``, so cross-run consumers (sweep benchmarks) diff
+        # these.  One scheduler call's own counts are in its result
+        # (``cache_stats``, from the call's repro.core.obs counters).
         self.hits_total = 0
         self.misses_total = 0
         self.evictions_total = 0
@@ -91,10 +92,12 @@ class SolveCache:
         if row is None:
             self.misses += 1
             self.misses_total += 1
+            obs.count("solve.misses")
             return None
         self._rows.move_to_end((tag, key))  # refresh LRU position
         self.hits += 1
         self.hits_total += 1
+        obs.count("solve.hits")
         return row
 
     def put(self, tag: str, key: bytes, value: np.ndarray) -> None:
@@ -105,7 +108,9 @@ class SolveCache:
             self._rows.popitem(last=False)
             self.evictions += 1
             self.evictions_total += 1
+            obs.count("solve.evictions")
 
+    @obs.spanned("solve.probe")
     def get_many(self, tag: str, keys: np.ndarray,
                  out: np.ndarray) -> tuple:
         """Batch :meth:`get` over the rows of a contiguous ``[m, k]`` key
@@ -139,6 +144,8 @@ class SolveCache:
         self.hits_total += hits
         self.misses += len(miss)
         self.misses_total += len(miss)
+        obs.count("solve.hits", hits)
+        obs.count("solve.misses", len(miss))
         return miss, miss_keys
 
     def put_keys(self, keys: list, values: list) -> None:
@@ -150,10 +157,12 @@ class SolveCache:
         rows.update(zip(keys, values))
         if len(rows) > self.maxsize:
             pop = rows.popitem
+            evicted = len(rows) - self.maxsize
             while len(rows) > self.maxsize:
                 pop(last=False)
-                self.evictions += 1
-                self.evictions_total += 1
+            self.evictions += evicted
+            self.evictions_total += evicted
+            obs.count("solve.evictions", evicted)
 
     def clear(self) -> None:
         self._rows.clear()
@@ -176,6 +185,17 @@ class SolveCache:
                 "hits_total": self.hits_total,
                 "misses_total": self.misses_total,
                 "evictions_total": self.evictions_total}
+
+    def call_stats(self) -> dict:
+        """:meth:`stats` for the open scheduler call: ``hits``, ``misses``,
+        ``evictions`` and ``hit_rate`` from the call's own counters
+        (:mod:`repro.core.obs`), ``rows`` and the ``*_total`` lifetime
+        counters from the cache."""
+        c = obs.counts()
+        hits, misses = c.get("solve.hits", 0), c.get("solve.misses", 0)
+        return {**self.stats(), "hits": hits, "misses": misses,
+                "evictions": c.get("solve.evictions", 0),
+                "hit_rate": hits / (hits + misses) if hits + misses else 0.0}
 
 
 #: The process-wide cache every ``dedup=True`` solver call shares.
@@ -222,9 +242,10 @@ def _materialize(pending) -> np.ndarray:
     """Resolve an in-flight solver result to a host f32 matrix.  Accepts a
     zero-arg callable (deferred multi-device gather), a JAX device array
     (blocks until the dispatched computation lands), or a plain ndarray."""
-    while callable(pending):
-        pending = pending()
-    return np.asarray(pending, np.float32)
+    with obs.span("solve.wait"):
+        while callable(pending):
+            pending = pending()
+        return np.asarray(pending, np.float32)
 
 
 class AsyncSolve:
@@ -271,23 +292,24 @@ class AsyncSolve:
         """Block on the dispatched solve and return ``[n, 8]`` f32 rows."""
         if self._result is None:
             miss = self._miss
-            if miss:
-                solved = _materialize(self._pending)[:len(miss)]
-                if solved.shape != (len(miss), SOL_COLS):
-                    raise ValueError(
-                        f"solver_fn returned {solved.shape}, expected "
-                        f"{(len(miss), SOL_COLS)}")
-                solved = np.ascontiguousarray(solved)
-                if len(miss) == self._out.shape[0]:
-                    self._out = solved
-                else:
-                    self._out[miss] = solved
-                if self._cache is not None:
-                    self._cache.put_keys(self._miss_keys, list(solved))
-            self._pending = None
-            self._miss_keys = None
-            self._result = self._out if self._inverse is None \
-                else self._out[self._inverse]
+            solved = _materialize(self._pending)[:len(miss)] if miss else None
+            with obs.span("solve.fill"):
+                if miss:
+                    if solved.shape != (len(miss), SOL_COLS):
+                        raise ValueError(
+                            f"solver_fn returned {solved.shape}, expected "
+                            f"{(len(miss), SOL_COLS)}")
+                    solved = np.ascontiguousarray(solved)
+                    if len(miss) == self._out.shape[0]:
+                        self._out = solved
+                    else:
+                        self._out[miss] = solved
+                    if self._cache is not None:
+                        self._cache.put_keys(self._miss_keys, list(solved))
+                self._pending = None
+                self._miss_keys = None
+                self._result = self._out if self._inverse is None \
+                    else self._out[self._inverse]
         return self._result
 
 
@@ -318,9 +340,11 @@ def solve_rows_async(keys: np.ndarray,
     keys = np.ascontiguousarray(np.asarray(keys, np.float32))
     if keys.ndim != 2 or keys.shape[1] != KEY_COLS:
         raise ValueError(f"keys must be [n, {KEY_COLS}], got {keys.shape}")
+    obs.count("solve.rows", keys.shape[0])
     if unique:
-        uniq, inverse = np.unique(keys, axis=0, return_inverse=True)
-        inverse = np.asarray(inverse).reshape(-1)  # numpy 2.x shape compat
+        with obs.span("solve.dedup"):
+            uniq, inverse = np.unique(keys, axis=0, return_inverse=True)
+            inverse = np.asarray(inverse).reshape(-1)  # numpy 2.x compat
     else:
         uniq, inverse = keys, None
     m = uniq.shape[0]
@@ -329,8 +353,13 @@ def solve_rows_async(keys: np.ndarray,
         miss, miss_keys = cache.get_many(tag, uniq, out)
     else:
         miss, miss_keys = list(range(m)), None
-    sub = uniq if len(miss) == m else uniq[miss]
-    pending = solver_fn(_pad_rows(sub)) if miss else None
+    pending = None
+    if miss:
+        with obs.span("solve.dispatch"):
+            sub = _pad_rows(uniq if len(miss) == m else uniq[miss])
+            pending = solver_fn(sub)
+        obs.count("solve.sent", sub.shape[0])
+        obs.count("solve.pad", sub.shape[0] - len(miss))
     return AsyncSolve(inverse, out, miss, miss_keys, pending, cache)
 
 
@@ -355,8 +384,10 @@ def solve_rows(keys: np.ndarray,
 
 def solution_to_rows(sol) -> np.ndarray:
     """Pack a ``DvfsSolution`` (8 same-length arrays) into ``[n, 8]`` f32 —
-    the cache's value layout (bool columns stored as 0.0/1.0)."""
-    return np.stack([np.asarray(f, np.float32) for f in sol], axis=1)
+    the cache's value layout (bool columns stored as 0.0/1.0).  Blocks on
+    device arrays until the solve has landed."""
+    with obs.span("solve.wait"):
+        return np.stack([np.asarray(f, np.float32) for f in sol], axis=1)
 
 
 def rows_to_solution(rows: np.ndarray) -> DvfsSolution:

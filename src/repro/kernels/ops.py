@@ -14,7 +14,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import solver_cache
+from repro.core import obs, solver_cache
 from repro.core.dvfs import DvfsParams, ScalingInterval, WIDE
 from repro.kernels import layout
 from repro.kernels.dvfs_opt import BT, DEFAULT_GRID, PAD_ROW, dvfs_solve_kernel
@@ -121,7 +121,10 @@ def dvfs_solve_matrix(mat: np.ndarray, *, grid: tuple = DEFAULT_GRID,
     if nd == 1:
         fut = dvfs_solve_kernel(jnp.asarray(mat), grid=grid,
                                 interpret=interpret)
-        return np.asarray(fut) if block else fut
+        if not block:
+            return fut
+        with obs.span("solve.wait"):
+            return np.asarray(fut)
     per_dev = -(-m // nd)
     chunk = -(-per_dev // BT) * BT  # whole kernel blocks per device
     if nd * chunk != m:
@@ -135,7 +138,10 @@ def dvfs_solve_matrix(mat: np.ndarray, *, grid: tuple = DEFAULT_GRID,
     def gather() -> np.ndarray:
         return np.concatenate([np.asarray(p) for p in parts], axis=0)[:m]
 
-    return gather() if block else gather
+    if not block:
+        return gather
+    with obs.span("solve.wait"):
+        return gather()
 
 
 def dvfs_solve(params: DvfsParams, allowed: np.ndarray,

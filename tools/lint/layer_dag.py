@@ -15,7 +15,9 @@ other:
 * ``repro.core.dvfs``       — the Eq. 1-4 power/time/energy model,
 * ``repro.core.cluster``    — state-free result records + Algorithm-3 helper,
 * ``repro.core.tasks``      — task-set synthesis,
-* ``repro.core.jobs``       — trace/job synthesis on top of tasks.
+* ``repro.core.jobs``       — trace/job synthesis on top of tasks,
+* ``repro.core.obs``        — the scheduler's spans and per-call counters
+  (every layer opens its spans and counts its work through it).
 
 ``EXTRA_EDGES`` documents the deliberate exceptions: the SSD-scan oracle in
 ``kernels/ref.py`` reuses the reference recurrence from ``models/ssm.py``
@@ -49,6 +51,7 @@ SHARED: FrozenSet[str] = frozenset({
     "repro.core.cluster",
     "repro.core.tasks",
     "repro.core.jobs",
+    "repro.core.obs",
 })
 
 #: Documented exceptions to the layer rule: importer -> allowed extra
