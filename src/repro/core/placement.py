@@ -34,6 +34,11 @@ Three placement paths per context, all bit-identical by construction:
   (:class:`_GroupPools`) of the engine's ``mu``/``class_id`` columns; the
   batch rounds alternate with the scalar rule per collision, and a lazy
   frontier heap finishes the group when batching stops paying for itself.
+  A group below a crossover size (``_SMALL_GROUP``, which falls as the
+  pool grows) skips the rounds and takes the scalar rule task by task
+  over the same pools: there a round's fixed cost (candidate stream,
+  carried-stream merge, some 25 array ops) exceeds ``k`` argmins over
+  the pool.  A paper day's arrival groups hold ~3 tasks.
 * :meth:`PlacementContext.place_group_select` — the pooled first-fit
   (``"ff"``) / best-fit (``"bf"``) path (offline ``lpt-ff``/``edf-bf`` and
   the online Algorithm-6 first-fit), per-task probes vectorized over the
@@ -77,6 +82,20 @@ _EPS = 1e-9
 # Result-neutral: streams are a coverage window over the same (mu, pair id)
 # order, and every consumer re-slices ``[:need]``.
 _STREAM_OVERSHOOT = 8
+
+# The crossover between the two worst-fit rules of
+# PlacementContext.place_group_vector.  A batched round costs about the
+# same whatever the group's size; the per-task scalar rule costs one argmin
+# over the class pool per task, dearer as the pool grows.  So a group of k
+# tasks over a pool of n pairs (its first task's class) takes the scalar
+# rule while k * (1 + n / _SMALL_GROUP_POOL) < _SMALL_GROUP: _SMALL_GROUP
+# is the crossover on an empty pool, and it halves at _SMALL_GROUP_POOL
+# pairs.
+# On a TPU v5e host the scalar rule won up to 48 tasks over 712 pairs and
+# up to 16 over 13,864 (benchmarks/placement_crossover.py); the rule puts
+# the crossover at 58.8 and 23.4.
+_SMALL_GROUP = 64
+_SMALL_GROUP_POOL = 8000
 
 #: pending θ-readjustment row: (assignment_index, task_index, window, class_id)
 PendingRow = Tuple[int, int, float, int]
@@ -677,8 +696,14 @@ class PlacementContext:
         ``mu`` tie — and resume batching while a round nets enough tasks to
         pay for itself; otherwise (power-on ramp, saturated frontier) the
         rest of the group runs the same scalar rule as a tight loop over
-        the pools with a lazy frontier heap.  Bit-identical to
-        :meth:`place_group_scalar` (rule ``"wf"``) by construction.
+        the pools with a lazy frontier heap.  A group of ``k`` tasks over
+        a pool of ``n`` pairs with ``k * (1 + n / _SMALL_GROUP_POOL) <
+        _SMALL_GROUP`` takes that scalar rule for every task instead: a
+        batched round costs about the same at any ``k``, ``k`` argmins
+        less below the crossover (~59 tasks over a paper day's ~700
+        pairs, ~23 over 14k; see ``_SMALL_GROUP``).
+        Bit-identical to :meth:`place_group_scalar` (rule ``"wf"``) by
+        construction.
 
         ``prep`` injects the group's :meth:`prepare_chunk` tuple; ``idx``
         and ``order`` are ignored then (the tuple already IS the ordered
@@ -1037,14 +1062,20 @@ class PlacementContext:
             dur_col[i0:] = du_l
             readj_col[i0:] = rj_l
 
-        # Alternate batch rounds with single scalar violators while batching
-        # pays for itself; a round that nets only a few tasks (power-on
-        # ramp, saturated frontier) costs more than the scalar rule, so
-        # finish the group scalar from there.
+        # A group below the crossover (_SMALL_GROUP) takes the scalar rule
+        # task by task.  Otherwise alternate batch rounds with single scalar
+        # violators while batching pays for itself; a round that nets only
+        # a few tasks (power-on ramp, saturated frontier) costs more than
+        # the scalar rule, so finish the group scalar from there.
         finish = finish_offline if (grain == 1 and not self.eng.server_mode
                                     and order_cols is None) else finish_scalar
         i = 0
         batched = 0
+        n0 = pool(int(prim[0]))[2]
+        if k * (_SMALL_GROUP_POOL + n0) < _SMALL_GROUP * _SMALL_GROUP_POOL:
+            for j in range(k):
+                place_one(j)
+            i = k
         while i < k:
             consumed = batch_round(i)
             i += consumed

@@ -136,7 +136,7 @@ def test_online_counters_are_pinned():
     assert pinned(r.counters) == {
         "tasks": 802, "solve.rows": 819, "solve.hits": 0,
         "solve.misses": 819, "solve.evictions": 0, "solve.sent": 1056,
-        "solve.pad": 237, "placement.batched": 7, "placement.scalar": 795,
+        "solve.pad": 237, "placement.batched": 0, "placement.scalar": 802,
         "placement.pinned": 0}
 
 

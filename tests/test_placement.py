@@ -9,6 +9,8 @@ online simulator uses.  These tests pin
 * the PR-1 offline golden energies, unchanged to 1e-9 rel (exact values
   re-recorded from the pre-refactor implementation at commit 2b52443,
   which reproduced the seed goldens of ``tests/test_engine.py`` to 1e-6);
+* online scalar/vector bit-identity on days whose arrival groups straddle
+  the small-group crossover of ``place_group_vector``;
 * the §5 wide-interval ~36% savings ceiling from ``theoretical_bound``
   and the e_bound reporting contract of both schedulers.
 """
@@ -16,7 +18,9 @@ online simulator uses.  These tests pin
 import numpy as np
 import pytest
 
-from repro.core import bounds, cluster as cl, machines, online, scheduling, tasks
+from repro.core import (bounds, cluster as cl, machines, online, placement,
+                        scheduling, tasks)
+from repro.core.faults import FaultTrace
 
 
 @pytest.fixture(scope="module")
@@ -83,6 +87,64 @@ def test_unknown_offline_placement_rejected(library):
     ts = tasks.generate_offline(0.02, seed=0, library=library)
     with pytest.raises(ValueError):
         scheduling.schedule_offline(ts, placement="warp")
+
+
+# ---------------------------------------------------------------------------
+# Online days around the small-group crossover: bit-identical.
+# ---------------------------------------------------------------------------
+
+
+def _group_sizes(kind):
+    """Group sizes around the crossover: a pool's pairs move it, but the
+    test days hold under a hundred, too few to move it by one task."""
+    s = placement._SMALL_GROUP
+    return {"below": [s - 1] * 8, "at": [s] * 8, "above": [s + 1] * 8,
+            "mix": [s - 1, 2 * s, 1, s, 3, s + 1, s - 1, 2, s]}[kind]
+
+
+def _sized_day(sizes, seed=5, gap=150.0):
+    """Tasks drawn the §5.1.3 way, one arrival group of each size every
+    ``gap`` slots (long enough that earlier tasks free pairs of servers
+    still on, so batched rounds find room), each task keeping its drawn
+    window."""
+    ts = tasks.generate_offline_n(sum(sizes), seed=seed)
+    arrival = np.repeat(gap * np.arange(1.0, len(sizes) + 1.0), sizes)
+    return tasks.TaskSet(arrival, arrival + ts.deadline, ts.params,
+                         ts.utilization)
+
+
+@pytest.mark.parametrize("faults", [False, True])
+@pytest.mark.parametrize("pipeline", [True, False])
+@pytest.mark.parametrize("mix", sorted(MIXES))
+@pytest.mark.parametrize("sizes", ["below", "at", "above", "mix"])
+def test_online_vector_bit_identical_around_small_groups(sizes, mix,
+                                                         pipeline, faults):
+    ts = _sized_day(_group_sizes(sizes))
+    kw = dict(l=2, theta=0.9, algorithm="edl", classes=MIXES[mix],
+              pipeline=pipeline, bound=False)
+    if faults:
+        kw["faults"] = FaultTrace.sample(
+            tasks.peak_pair_estimate(ts) // 2, float(ts.arrival[-1]),
+            mtbf=400.0, mttr=50.0, seed=1)
+    r_s = online.schedule_online(ts, placement="scalar", **kw)
+    r_v = online.schedule_online(ts, placement="vector", **kw)
+    assert r_v.e_total == r_s.e_total           # bit-for-bit
+    assert (r_v.e_idle, r_v.e_overhead, r_v.violations, r_v.n_pairs) == \
+        (r_s.e_idle, r_s.e_overhead, r_s.violations, r_s.n_pairs)
+    assert r_v.fault_stats == r_s.fault_stats
+    if faults:
+        assert r_v.fault_stats["failures"] > 0
+    assert [_fields(a) for a in r_v.assignments] == \
+        [_fields(a) for a in r_s.assignments]
+    batched = r_v.counters["placement.batched"]
+    if sizes == "below":
+        # no pool of the day holds enough pairs to move the crossover
+        assert (placement._SMALL_GROUP - 1) * (
+            placement._SMALL_GROUP_POOL + r_v.n_pairs) \
+            < placement._SMALL_GROUP * placement._SMALL_GROUP_POOL
+        assert batched == 0
+    elif sizes in ("at", "above"):
+        assert batched > 0
 
 
 # ---------------------------------------------------------------------------
