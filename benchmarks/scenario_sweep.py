@@ -53,7 +53,8 @@ DEFAULT_MIXES = (
     ("gtx-1080ti",),
     ("gtx-1080ti", "tpu-v5e"),
 )
-FULL_MIXES = DEFAULT_MIXES + (("gtx-1080ti", "tpu-v5e", "v100-sxm2"),)
+FULL_MIXES = DEFAULT_MIXES + (("gtx-1080ti", "tpu-v5e", "v100-sxm2"),
+                              ("tesla-t4", "tesla-p100", "v100-sxm2"))
 
 
 def _scaled_mix(names, delta_scale: float):

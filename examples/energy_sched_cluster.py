@@ -26,6 +26,12 @@ DVFS optimum on every class and sends it to the min-energy feasible one
     PYTHONPATH=src python examples/energy_sched_cluster.py \
         --classes gtx-1080ti,tpu-v5e,v100-sxm2
 
+or the three GPU generations of the Alibaba PAI 2020 fleet (T4, P100 and
+V100 pairs, each with its single memory clock)::
+
+    PYTHONPATH=src python examples/energy_sched_cluster.py \
+        --classes tesla-t4,tesla-p100,v100-sxm2
+
 Falls back to a representative synthetic roofline table if the dry-run
 JSONs are absent.
 """

@@ -127,22 +127,50 @@ TPU_V5E = MachineClass(
     delta_on=dvfs.TPU_V5E_CHIP["delta_on"],
 )
 
-#: A Volta-class datacenter GPU: ~250 W envelope, ~1.5x the reference
-#: throughput, a slightly wider voltage floor than the 1080Ti's NARROW box
-#: (fit ranges in the style of the paper's published table).
-V100_SXM2 = MachineClass(
-    "v100-sxm2",
-    interval=ScalingInterval(v_min=0.75, v_max=1.2, fc_min=0.55,
-                             fm_min=0.65, fm_max=1.1),
-    speed=1.5,
-    power_scale=250.0 / REF_P_PEAK,
-    p0_frac=0.35,
-    gamma_frac=0.18,
-    p_idle=45.0,
-    delta_on=110.0,
-)
+# Datacenter GPUs from their spec sheets, by one rule against the reference
+# part (the GTX 1080 Ti: 11.34 TFLOPS FP32, 250 W board power):
+#
+# * ``speed``       = the part's FP32 peak / 11.34 TFLOPS;
+# * ``power_scale`` = the part's board power / 250 W, with the reference
+#   fit's static / memory / core split kept (``p0_frac = gamma_frac = None``);
+# * ``p_idle``      = the paper's per-pair idle parts (Sec. 5.1.2), 24 W of
+#   CPU plus the GPU's 13 W scaled by ``power_scale``;
+# * ``delta_on``    = ``p_idle * 90 / 37``, the paper's turn-on energy in
+#   proportion, so that ``floor(delta_on / p_idle)`` stays ``cl.RHO``;
+# * ``interval``    = :data:`FIXED_MEMORY_CLOCK`: the reference part's
+#   realistic core box (NVIDIA publishes no voltages) and the single memory
+#   clock these parts support (877 MHz V100, 715 MHz P100, 5001 MHz T4).
 
-REGISTRY = {c.name: c for c in (GTX_1080TI, TPU_V5E, V100_SXM2)}
+REF_FP32_TFLOPS = 11.34
+REF_BOARD_W = 250.0
+CPU_IDLE_W = 24.0
+GPU_IDLE_W = 13.0
+
+#: The GTX-1080Ti's NARROW core side with the memory clock held at nominal.
+FIXED_MEMORY_CLOCK = ScalingInterval(v_min=0.8, v_max=1.24, fc_min=0.89,
+                                     fm_min=1.0, fm_max=1.0)
+
+
+def spec_sheet_class(name: str, fp32_tflops: float,
+                     board_w: float) -> MachineClass:
+    """A datacenter GPU pair class by the spec-sheet rule above."""
+    power_scale = board_w / REF_BOARD_W
+    p_idle = CPU_IDLE_W + GPU_IDLE_W * power_scale
+    return MachineClass(name, interval=FIXED_MEMORY_CLOCK,
+                        speed=fp32_tflops / REF_FP32_TFLOPS,
+                        power_scale=power_scale, p_idle=p_idle,
+                        delta_on=p_idle * cl.DELTA_ON / cl.P_IDLE)
+
+
+#: Tesla T4, 16 GB GDDR6: 8.1 TFLOPS FP32, 70 W.
+TESLA_T4 = spec_sheet_class("tesla-t4", 8.1, 70.0)
+#: Tesla P100 PCIe 16 GB: 9.3 TFLOPS FP32, 250 W.
+TESLA_P100 = spec_sheet_class("tesla-p100", 9.3, 250.0)
+#: Tesla V100 SXM2 16 GB: 15.7 TFLOPS FP32, 300 W.
+V100_SXM2 = spec_sheet_class("v100-sxm2", 15.7, 300.0)
+
+REGISTRY = {c.name: c for c in (GTX_1080TI, TPU_V5E, TESLA_T4, TESLA_P100,
+                                V100_SXM2)}
 
 ClassSpec = Union[str, MachineClass]
 

@@ -60,6 +60,7 @@ COUNTERS = (
     "placement.batched",  # tasks placed by batched prefix rounds
     "placement.scalar",   # tasks placed by a per-task rule
     "placement.pinned",   # deadline-prior tasks pinned to fresh pairs
+    "placement.fallback",  # tasks on a pair in use of a non-primary class
     "gc.collections",     # garbage collections during the call
 )
 

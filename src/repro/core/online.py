@@ -92,8 +92,8 @@ from repro.core.dvfs import ScalingInterval
 from repro.core.engine import ClusterEngine
 from repro.core.faults import FaultInjector, FaultTrace, make_degrade
 from repro.core.placement import PendingRow, PlacementContext
-from repro.core.scheduling import (chosen_feasibility, count_violations,
-                                   fill_readjusted)
+from repro.core.scheduling import (chosen_feasibility, count_fallback,
+                                   count_violations, fill_readjusted)
 from repro.core.single_task import TaskConfig
 from repro.core.tasks import TaskSet
 from repro.kernels import layout
@@ -146,8 +146,8 @@ def online_configs(task_set: TaskSet, mcs, use_dvfs: bool = True,
 # dropped/double-consumed AsyncSolve handles and reads of the full-horizon
 # views before the sync point.
 
-#: Target chunk size (tasks) for the pipelined driver: whole arrival groups
-#: are accumulated until the count reaches this.  Large enough that one
+#: Chunk size (tasks) for the pipelined driver: whole arrival groups are
+#: accumulated while the count stays within this.  Large enough that one
 #: batched solve amortizes its dispatch and per-chunk host bookkeeping
 #: (eager op dispatch overhead is per chunk, not per row), small enough
 #: that a 1M-task horizon still pipelines ~30 chunks deep.
@@ -155,15 +155,17 @@ PIPELINE_CHUNK_TASKS = 32768
 
 
 def _chunk_groups(groups, target: int):
-    """Cut the (slot, idx) arrival groups into consecutive runs of >=
-    ``target`` tasks (always whole groups; the tail run may be smaller)."""
+    """Cut the (slot, idx) arrival groups into consecutive runs of at most
+    ``target`` tasks (always whole groups; a group larger than ``target``
+    is a run of its own).  A full run's solve then pads to one shape,
+    ``target`` rows, instead of straddling it by a group's worth."""
     chunks, cur, count = [], [], 0
     for g in groups:
-        cur.append(g)
-        count += g[1].size
-        if count >= target:
+        if cur and count + g[1].size > target:
             chunks.append(cur)
             cur, count = [], 0
+        cur.append(g)
+        count += g[1].size
     if cur:
         chunks.append(cur)
     return chunks
@@ -520,6 +522,7 @@ def schedule_online(task_set: TaskSet, l: int = 1, theta: float = 1.0,
         e_run = float(sum(a.energy for a in assignments))
         violations = count_violations(
             assignments, deadline, chosen_feasibility(cfgs, assignments, n))
+        count_fallback(assignments, order_cls)
         mk = max((a.finish for a in assignments), default=0.0)
         e_bound = bounds.theoretical_bound(
             task_set, interval=interval, classes=mcs, l=l,

@@ -171,6 +171,21 @@ def chosen_feasibility(cfgs: Sequence[TaskConfig],
     return feas
 
 
+def count_fallback(assignments: List[cl.Assignment],
+                   order_cls: np.ndarray) -> None:
+    """Count the tasks whose live record lies on a class other than their
+    primary one (``placement.fallback``).  Every rule opens a fresh pair
+    of the task's primary class, so such a record is a pair in use that
+    the task fell back to.  One class never counts."""
+    if order_cls.shape[0] < 2 or not assignments:
+        return
+    t = np.fromiter((a.task for a in assignments if not a.failed), np.int64)
+    cid = np.fromiter((a.class_id for a in assignments if not a.failed),
+                      np.int64)
+    obs.count("placement.fallback",
+              int(np.count_nonzero(cid != order_cls[0][t])))
+
+
 @obs.call("schedule.offline")
 def schedule_offline(task_set: TaskSet, l: int = 1, theta: float = 1.0,
                      algorithm: str = "edl", use_dvfs: bool = True,
@@ -281,6 +296,7 @@ def schedule_offline(task_set: TaskSet, l: int = 1, theta: float = 1.0,
         e_idle, e_overhead, n_servers = eng.finalize()
         violations = count_violations(
             assignments, deadline, chosen_feasibility(cfgs, assignments, n))
+        count_fallback(assignments, order_cls)
         e_bound = bounds.theoretical_bound(
             task_set, interval=interval, classes=mcs,
             dedup=dedup).e_bound if bound else 0.0

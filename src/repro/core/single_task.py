@@ -16,7 +16,8 @@ Two sub-problems, both reduced to a 1-D minimization:
   optimum sits on the time boundary ``t(fc, fm) = allowed``.  Parametrizing by
   ``fm``, the required core frequency is
   ``fc_req(fm) = D delta / (allowed - t0 - D (1 - delta) / fm)`` and
-  ``V = max(v_min, g1^{-1}(fc))``; again a 1-D search over ``fm``.
+  ``V = max(v_min, g1^{-1}(fc))``; again a 1-D search over ``fm``, or, on
+  a box with a single memory clock (``fm_min == fm_max``), that clock.
 
 Everything is vectorized over a batch of tasks and jit-compatible; it is both
 the production solver and the oracle for the ``dvfs_opt`` Pallas kernel.
@@ -145,8 +146,11 @@ def _boundary_optimum(params: DvfsParams, allowed, interval: ScalingInterval):
         return _deadline_energy_of_fm(params, fm, allowed, interval)[0]
 
     lo = jnp.full_like(params.big_d, interval.fm_min)
-    hi = jnp.full_like(params.big_d, interval.fm_max)
-    fm = _grid_then_golden(efm, lo, hi)
+    if interval.fm_min == interval.fm_max:
+        fm = lo                  # a fixed memory clock: one boundary point
+    else:
+        hi = jnp.full_like(params.big_d, interval.fm_max)
+        fm = _grid_then_golden(efm, lo, hi)
     _, (v, fc) = _deadline_energy_of_fm(params, fm, allowed, interval)
     return v, fc, fm
 

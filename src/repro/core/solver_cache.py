@@ -57,6 +57,12 @@ from repro.kernels.layout import DvfsSolution, KEY_COLS, SOL_COLS
 #: device work.
 _MIN_PAD = 8
 _PAD_BLOCK = 1024
+#: Readjustment (deadline-boundary) batches pad to at least this many
+#: rows.  Their size swings with the day (tens of rows on a paper day, a
+#: few to ~275 on a 40k-task fleet day), so powers of two from 8 would
+#: give several shapes of which some turn up once in tens of days and
+#: compile mid-run; one shape costs a few hundred padded rows a batch.
+_READJUST_PAD = 512
 
 
 class SolveCache:
@@ -222,14 +228,14 @@ def build_keys(param_cols: Sequence[np.ndarray], allowed: np.ndarray,
     return np.ascontiguousarray(keys, np.float32)
 
 
-def _pad_rows(mat: np.ndarray) -> np.ndarray:
+def _pad_rows(mat: np.ndarray, floor: int = _MIN_PAD) -> np.ndarray:
     """Pad the row count up to the solver shape grid — the next power of
-    two (>= _MIN_PAD) below _PAD_BLOCK, the next _PAD_BLOCK multiple above
+    two (>= ``floor``) below _PAD_BLOCK, the next _PAD_BLOCK multiple above
     it — replicating the last row, which is safe because every solver is
     row-independent."""
     k = mat.shape[0]
     if k <= _PAD_BLOCK:
-        k_pad = max(_MIN_PAD, 1 << (k - 1).bit_length())
+        k_pad = max(floor, 1 << (k - 1).bit_length())
     else:
         k_pad = -(-k // _PAD_BLOCK) * _PAD_BLOCK
     if k_pad == k:
@@ -356,7 +362,9 @@ def solve_rows_async(keys: np.ndarray,
     pending = None
     if miss:
         with obs.span("solve.dispatch"):
-            sub = _pad_rows(uniq if len(miss) == m else uniq[miss])
+            sub = _pad_rows(uniq if len(miss) == m else uniq[miss],
+                            _READJUST_PAD if uniq[0, layout.READJUST] == 1.0
+                            else _MIN_PAD)
             pending = solver_fn(sub)
         obs.count("solve.sent", sub.shape[0])
         obs.count("solve.pad", sub.shape[0] - len(miss))

@@ -252,3 +252,91 @@ def test_registry_lookup_and_errors():
             tasks.generate_offline(0.01, seed=0), algorithm="edl",
             cfg=scheduling.default_config(tasks.generate_offline(0.01, seed=0)),
             classes=("gtx-1080ti", "tpu-v5e"))
+
+
+# ---------------------------------------------------------------------------
+# Datacenter GPUs by the spec-sheet rule, and their fixed memory clock.
+# ---------------------------------------------------------------------------
+
+PAI_FLEET = ("tesla-t4", "tesla-p100", "v100-sxm2")
+
+
+@pytest.mark.parametrize("name,tflops,watts", [("tesla-t4", 8.1, 70.0),
+                                               ("tesla-p100", 9.3, 250.0),
+                                               ("v100-sxm2", 15.7, 300.0)])
+def test_spec_sheet_classes_follow_the_rule(name, tflops, watts):
+    mc = machines.REGISTRY[name]
+    assert mc.speed == tflops / 11.34
+    assert mc.power_scale == watts / 250.0
+    assert mc.p0_frac is None and mc.gamma_frac is None
+    assert mc.p_idle == 24.0 + 13.0 * watts / 250.0
+    assert mc.delta_on == pytest.approx(mc.p_idle * 90.0 / 37.0, rel=1e-15)
+    assert int(mc.delta_on // mc.p_idle) == 2      # the run's rho
+    assert mc.interval == machines.FIXED_MEMORY_CLOCK
+    assert mc.interval.fm_min == mc.interval.fm_max == 1.0
+    assert (mc.interval.v_min, mc.interval.v_max, mc.interval.fc_min) == \
+        (dvfs.NARROW.v_min, dvfs.NARROW.v_max, dvfs.NARROW.fc_min)
+
+
+def test_fixed_memory_clock_box_jnp_and_kernel(library):
+    """On a box with ``fm_min == fm_max`` every row sits at that clock
+    exactly, on the jnp solver (closed form on the deadline boundary) and
+    on the Pallas kernel (its fm sweep collapses), with no NaN and the
+    kernel within the oracle tolerance of the NARROW-interval test."""
+    import jax.numpy as jnp
+
+    from repro.core import single_task
+    from repro.kernels.dvfs_opt import dvfs_solve_kernel
+
+    box = machines.FIXED_MEMORY_CLOCK
+    ts = tasks.generate_offline(0.06, seed=29, library=library)
+    mcs = machines.get_classes(PAI_FLEET)
+    allowed = np.asarray(ts.deadline - ts.arrival, np.float64)
+    for mc in mcs:
+        p = mc.adapt(ts.params)
+        for solver in (single_task.solve_with_deadline,
+                       single_task.solve_on_boundary):
+            sol = solver(p, allowed, box)
+            assert np.all(np.asarray(sol.fm) == 1.0)
+            assert all(np.all(np.isfinite(np.asarray(f))) for f in sol[:6])
+        # the search the closed form skips would land on the same bits
+        p32 = dvfs.DvfsParams(*(jnp.asarray(f, jnp.float32)
+                                for f in p.astuple()))
+        one = jnp.ones_like(p32.big_d)
+        fm = single_task._grid_then_golden(
+            lambda f: single_task._deadline_energy_of_fm(
+                p32, f, jnp.asarray(allowed, jnp.float32), box)[0], one, one)
+        assert np.all(np.asarray(fm) == 1.0)
+    for readjust in (False, True):
+        mat = _class_matrix(ts, mcs, readjust=readjust)
+        out = np.asarray(dvfs_solve_kernel(jnp.asarray(mat), interpret=True))
+        exp = ref.dvfs_solve_ref(mat)
+        assert np.all(np.isfinite(out)) and np.all(np.isfinite(exp))
+        assert np.all(out[:, 2] == 1.0) and np.all(exp[:, 2] == 1.0)
+        ok = (out[:, 7] > .5) & (exp[:, 7] > .5)
+        rel = np.abs(out[ok, 5] - exp[ok, 5]) / exp[ok, 5]
+        assert float(np.max(rel)) < 1e-5
+
+
+@pytest.mark.parametrize("pipeline", [True, False])
+def test_pai_fleet_vector_matches_scalar(pipeline, library):
+    """Vector and scalar placement give the same records on the three
+    fixed-memory-clock classes, with class fallback on the path."""
+    from repro.core import solver_cache
+
+    ts = tasks.generate_trace(2000, pattern="diurnal", horizon=120, seed=6,
+                              library=library)
+    runs = {}
+    for mode in ("vector", "scalar"):
+        solver_cache.GLOBAL_CACHE.clear()
+        runs[mode] = online.schedule_online(
+            ts, l=8, theta=0.9, algorithm="edl", interval=dvfs.NARROW,
+            classes=PAI_FLEET, placement=mode, pipeline=pipeline,
+            bound=False)
+    v, s = runs["vector"], runs["scalar"]
+    assert v.e_total == s.e_total and v.violations == s.violations
+    assert v.assignments == s.assignments
+    assert v.counters["placement.fallback"] == \
+        s.counters["placement.fallback"] > 0
+    assert {a.fm for a in v.assignments} == {1.0}
+    assert {a.class_id for a in v.assignments} <= {0, 1, 2}

@@ -119,9 +119,9 @@ def test_offline_counters_are_pinned():
     r = scheduling.schedule_offline(ts, **KW)
     assert pinned(r.counters) == {
         "tasks": 602, "solve.rows": 1056, "solve.hits": 0,
-        "solve.misses": 631, "solve.evictions": 0, "solve.sent": 1056,
-        "solve.pad": 425, "placement.batched": 0, "placement.scalar": 512,
-        "placement.pinned": 90}
+        "solve.misses": 631, "solve.evictions": 0, "solve.sent": 1536,
+        "solve.pad": 905, "placement.batched": 0, "placement.scalar": 512,
+        "placement.pinned": 90, "placement.fallback": 0}
     # a warm rerun: every row a hit, nothing sent
     r2 = scheduling.schedule_offline(ts, **KW)
     assert pinned(r2.counters) == {**pinned(r.counters), "solve.hits": 631,
@@ -135,9 +135,9 @@ def test_online_counters_are_pinned():
     r = online.schedule_online(ts, **KW)
     assert pinned(r.counters) == {
         "tasks": 802, "solve.rows": 819, "solve.hits": 0,
-        "solve.misses": 819, "solve.evictions": 0, "solve.sent": 1056,
-        "solve.pad": 237, "placement.batched": 0, "placement.scalar": 802,
-        "placement.pinned": 0}
+        "solve.misses": 819, "solve.evictions": 0, "solve.sent": 1536,
+        "solve.pad": 717, "placement.batched": 0, "placement.scalar": 802,
+        "placement.pinned": 0, "placement.fallback": 0}
 
 
 @pytest.mark.parametrize("algorithm", ["edl", "bin"])
@@ -162,6 +162,34 @@ def test_counters_add_up(entry, placement, algorithm):
     assert c["solve.rows"] >= c["solve.hits"] + c["solve.misses"]
     assert r.cache_stats["hits"] == c["solve.hits"]
     assert r.cache_stats["misses"] == c["solve.misses"]
+
+
+FLEET = ("tesla-t4", "tesla-p100", "v100-sxm2")
+
+
+@pytest.mark.parametrize("placement", ["vector", "scalar"])
+@pytest.mark.parametrize("entry,algorithm,fallback", [
+    ("online", "edl", 396), ("online", "bin", 339),
+    ("offline", "edl", 207), ("offline", "lpt-ff", 40)])
+def test_fallback_counter_is_pinned(entry, algorithm, fallback, placement):
+    """On three classes every placement rule counts the tasks it puts on
+    a pair in use of a class other than their primary one, and the vector
+    and scalar paths count alike."""
+    if entry == "offline":
+        r = scheduling.schedule_offline(offline_set(), classes=FLEET,
+                                        placement=placement,
+                                        algorithm=algorithm, **KW)
+    else:
+        r = online.schedule_online(online_set(), classes=FLEET,
+                                   placement=placement, algorithm=algorithm,
+                                   **KW)
+    assert r.counters["placement.fallback"] == fallback
+
+
+@pytest.mark.parametrize("classes", [None, ("v100-sxm2",)])
+def test_fallback_counter_is_zero_on_one_class(classes):
+    r = online.schedule_online(online_set(), classes=classes, **KW)
+    assert r.counters["placement.fallback"] == 0
 
 
 def test_cache_stats_come_from_the_call_not_the_cache():

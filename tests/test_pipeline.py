@@ -270,6 +270,36 @@ def test_pad_rows_shape_grid(k, expect):
             p[k:], np.broadcast_to(m[-1], (expect - k, 2)))
 
 
+@pytest.mark.parametrize("readjust,k,expect", [
+    (1.0, 1, 512), (1.0, 9, 512), (1.0, 300, 512), (1.0, 513, 1024),
+    (1.0, 1500, 2048), (0.0, 9, 16), (0.0, 300, 512)])
+def test_readjust_batches_pad_to_one_shape(readjust, k, expect):
+    """Readjustment batches (key column ``readjust`` set) pad to at least
+    512 rows, so a day's few-to-hundreds readjusts share one solver shape;
+    deadline batches keep the grid from 8."""
+    keys = np.random.default_rng(k).random((k, KEY_COLS)).astype(np.float32)
+    keys[:, solver_cache.layout.READJUST] = readjust
+    calls = []
+    got = solve_rows_async(keys, _toy_solver(calls), tag="t", cache=None,
+                           unique=False).result()
+    assert calls == [expect]
+    assert np.array_equal(got[:, 0], keys[:, 0] * 2.0)
+
+
+@pytest.mark.parametrize("sizes,expect", [
+    ([3, 3, 2], [[3, 3, 2]]),
+    ([4, 4, 4, 4], [[4, 4], [4, 4]]),
+    ([5, 4, 2, 9, 1], [[5], [4, 2], [9], [1]]),
+    ([8, 1], [[8], [1]])])
+def test_chunks_hold_at_most_the_target(sizes, expect):
+    """Whole groups, each run within the target (one that alone exceeds
+    it runs by itself), so a full run's solve pads to the target's shape."""
+    groups = [(s, np.arange(n)) for s, n in enumerate(sizes)]
+    chunks = online._chunk_groups(groups, 8)
+    assert [[g[1].size for g in c] for c in chunks] == expect
+    assert [g for c in chunks for g in c] == groups
+
+
 # ---------------------------------------------------------------------------
 # Cache counters: per-run reset vs lifetime totals, hit pinning.
 # ---------------------------------------------------------------------------
