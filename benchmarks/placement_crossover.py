@@ -69,12 +69,12 @@ def shared(ctx):
 
 def snapshot(ctx):
     """A copy of the context without its records."""
-    held = ctx.assignments, ctx.pending
-    ctx.assignments, ctx.pending = [], []
+    held = ctx.records, ctx.pending
+    ctx.records, ctx.pending = cl.Records(), []
     try:
         return copy.deepcopy(ctx, shared(ctx))
     finally:
-        ctx.assignments, ctx.pending = held
+        ctx.records, ctx.pending = held
 
 
 def time_group(snap, idx, t_now):

@@ -42,8 +42,8 @@ MAX_PAIRS = 2048     # cluster-wide pair budget, S5.1.2
 class Assignment:
     """One scheduled task: where, when, and at which DVFS setting.
 
-    ``slots=True``: online horizons carry one record per task (100k+), so
-    construction cost and footprint matter."""
+    The object view of one row of :class:`Records`, built only on demand
+    (:attr:`ScheduleResult.assignments`)."""
 
     task: int
     pair: int
@@ -63,6 +63,88 @@ class Assignment:
     failed: bool = False
 
 
+#: The DVFS setting columns of a record, in the row order of
+#: :attr:`Records.setting` (and of the placement's stacked config columns).
+SETTING = ("v", "fc", "fm", "power", "energy")
+
+
+def _setting_column(i: int) -> property:
+    return property(lambda self: self.setting[i],
+                    doc=f"The ``{SETTING[i]}`` row of :attr:`setting`.")
+
+
+class Records:
+    """A scheduler call's records as columns: row ``r`` holds the fields of
+    one :class:`Assignment`, and ``n`` rows are in use.
+
+    Each column is an array of the store's capacity; read ``col[:n]``.  The
+    five setting columns are the rows of one ``[5, capacity]`` block,
+    :attr:`setting`, so a placed group's settings are one gather from the
+    config columns stacked the same way.  The store grows by doubling;
+    rows are only ever added, never removed (pending θ-readjustment rows and
+    fault tracking address them by index), and a new row starts with
+    ``readjusted`` and ``failed`` false.  A call's records are numbers in
+    arrays, so they leave nothing for the garbage collector to trace.
+    """
+
+    __slots__ = ("n", "task", "pair", "class_id", "start", "finish",
+                 "setting", "readjusted", "failed")
+
+    _FLAT = {"task": np.int64, "pair": np.int64, "class_id": np.int64,
+             "start": np.float64, "finish": np.float64, "readjusted": bool,
+             "failed": bool}
+
+    def __init__(self, capacity: int = 0):
+        self.n = 0
+        self._resize(max(int(capacity), 8))
+
+    def __len__(self) -> int:
+        return self.n
+
+    def _resize(self, cap: int):
+        n = self.n
+        for name, dtype in self._FLAT.items():
+            col = np.zeros(cap, dtype)
+            if n:
+                col[:n] = getattr(self, name)[:n]
+            setattr(self, name, col)
+        setting = np.zeros((len(SETTING), cap))
+        if n:
+            setting[:, :n] = self.setting[:, :n]
+        self.setting = setting
+
+    v, fc, fm, power, energy = map(_setting_column, range(len(SETTING)))
+
+    def add(self, k: int) -> int:
+        """Take ``k`` new rows; returns the index of the first."""
+        b = self.n
+        if b + k > self.task.shape[0]:
+            self._resize(max(b + k, 2 * self.task.shape[0]))
+        self.n = b + k
+        return b
+
+    def append(self, task: int, pair: int, start: float, finish: float,
+               v: float, fc: float, fm: float, power: float, energy: float,
+               readjusted: bool = False, class_id: int = 0) -> int:
+        """One record (the per-task rules); returns its row."""
+        i = self.add(1)
+        self.task[i] = task
+        self.pair[i] = pair
+        self.class_id[i] = class_id
+        self.start[i] = start
+        self.finish[i] = finish
+        self.setting[:, i] = (v, fc, fm, power, energy)
+        self.readjusted[i] = readjusted
+        return i
+
+    def assignments(self) -> List[Assignment]:
+        """The rows as :class:`Assignment` objects (an object per row)."""
+        n = self.n
+        return list(map(Assignment, *(
+            getattr(self, f.name)[:n].tolist()
+            for f in dataclasses.fields(Assignment))))
+
+
 @dataclasses.dataclass
 class ScheduleResult:
     """Outcome of a scheduling run (energies in Joule-equivalent W x time)."""
@@ -74,7 +156,8 @@ class ScheduleResult:
     n_pairs: int
     n_servers: int
     violations: int
-    assignments: List[Assignment]
+    #: the call's records as columns
+    records: Records
     makespan: float = 0.0
     feasible_pairs: bool = True
     #: the §5 analytical lower bound on e_total for this task set
@@ -91,6 +174,17 @@ class ScheduleResult:
     #: this call's counters (repro.core.obs.COUNTERS); None for a call made
     #: inside another scheduler call, whose record counts it.
     counters: dict = None
+    _assignments: list = dataclasses.field(default=None, init=False,
+                                           repr=False, compare=False)
+
+    @property
+    def assignments(self) -> List[Assignment]:
+        """The records as :class:`Assignment` objects, built from
+        :attr:`records` on first access and kept (read-only: the columns
+        are the result)."""
+        if self._assignments is None:
+            self._assignments = self.records.assignments()
+        return self._assignments
 
     @property
     def e_total(self) -> float:

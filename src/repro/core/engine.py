@@ -319,7 +319,7 @@ class ClusterEngine:
         in-flight task dies at the crash), (b) subtracts ``busy_rollback``
         (the caller-computed booked-busy portion past ``t``; the
         :class:`repro.core.faults.FaultInjector` derives it from the
-        orphaned assignment records), and (c) marks the pair ineligible.
+        orphaned records), and (c) marks the pair ineligible.
         A server whose pairs have ALL failed while powered on is a hard
         crash: its on-span is booked up to ``t`` (no ``rho`` power-off
         tail — the machine lost power, it did not drain) and it leaves the

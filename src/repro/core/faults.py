@@ -157,7 +157,7 @@ class FaultInjector:
     Owned by :func:`repro.core.online.schedule_online`; the loop calls
     :meth:`advance` before each arrival group (applying every event up to
     the slot), :meth:`register` after each placement (tracking which
-    assignment records live on which pair), and :meth:`finalize_records`
+    record rows live on which pair), and :meth:`finalize_records`
     once after the deferred readjust solves (re-pricing truncated records
     from their *final* power — a truncated θ-readjusted record only knows
     its power after the batch solve).
@@ -183,10 +183,11 @@ class FaultInjector:
 
     # -- tracking ------------------------------------------------------------
     def register(self, base: int):
-        """Track ``assignments[base:]`` (one placement's records) by pair."""
-        asn = self.ctx.assignments
-        for i in range(base, len(asn)):
-            self.pair_tasks.setdefault(asn[i].pair, []).append(i)
+        """Track the record rows from ``base`` on (one placement's) by
+        pair."""
+        rec = self.ctx.records
+        for i, pid in enumerate(rec.pair[base:rec.n].tolist(), base):
+            self.pair_tasks.setdefault(pid, []).append(i)
 
     # -- replay --------------------------------------------------------------
     def advance(self, t: float):
@@ -210,7 +211,7 @@ class FaultInjector:
         l = self.eng.l
         lo = e.server * l
         pids = np.arange(lo, lo + l, dtype=np.int64)
-        asn = self.ctx.assignments
+        rec = self.ctx.records
         rollback = np.zeros(l)
         orphans: List[int] = []
         for j, pid in enumerate(pids.tolist()):
@@ -218,23 +219,24 @@ class FaultInjector:
             if not rows:
                 continue
             for ai in rows:
-                a = asn[ai]
-                if a.failed or a.finish <= e.t + _EPS:
+                start = float(rec.start[ai])
+                finish = float(rec.finish[ai])
+                if rec.failed[ai] or finish <= e.t + _EPS:
                     continue          # already truncated, or completed by t
-                if a.start < e.t - _EPS:
+                if start < e.t - _EPS:
                     # in-flight: the task dies mid-run; energy up to the
                     # crash is wasted but billed (the machine burned it)
-                    rollback[j] += a.finish - e.t
-                    asn[ai] = dataclasses.replace(a, finish=e.t, failed=True)
+                    rollback[j] += finish - e.t
+                    rec.finish[ai] = e.t
                 else:
                     # queued but unstarted: tombstone (records are
                     # index-addressed by the pending readjust rows, so
                     # they are never removed, only zero-spanned)
-                    rollback[j] += a.finish - a.start
-                    asn[ai] = dataclasses.replace(a, finish=a.start,
-                                                  failed=True)
+                    rollback[j] += finish - start
+                    rec.finish[ai] = start
+                rec.failed[ai] = True
                 self.truncated.append(ai)
-                orphans.append(a.task)
+                orphans.append(int(rec.task[ai]))
             rows.clear()              # pair is down: nothing left to track
         failed = self.eng.fail_pairs(e.t, pids, busy_rollback=rollback)
         if failed.size == 0:
@@ -243,7 +245,7 @@ class FaultInjector:
         self.stats["failures"] += 1
         self.stats["orphans"] += len(orphans)
         if orphans:
-            base = len(asn)
+            base = rec.n
             restarted, degraded = self.ctx.place_orphans(
                 np.asarray(orphans, dtype=np.int64), e.t, self.rule,
                 degrade=self.degrade)
@@ -270,11 +272,9 @@ class FaultInjector:
         boundary solve, and the batch writer prices the full window — this
         pass rewrites the energy to the span the pair actually ran
         (tombstones price to exactly 0)."""
-        asn = self.ctx.assignments
-        for ai in self.truncated:
-            a = asn[ai]
-            asn[ai] = dataclasses.replace(
-                a, energy=a.power * (a.finish - a.start))
+        rec = self.ctx.records
+        ai = np.asarray(self.truncated, dtype=np.int64)
+        rec.energy[ai] = rec.power[ai] * (rec.finish[ai] - rec.start[ai])
 
 
 def make_degrade(task_set, mcs, interval, use_dvfs: bool) -> Callable:

@@ -17,8 +17,10 @@ one opens its span but no record: its work is counted by the outermost
 call, and its result's ``counters`` stays ``None``.
 
 While an outermost call is open, a ``gc.callbacks`` hook opens a
-``host.gc`` span over each garbage collection and counts it.  The hook is
-registered on call entry and removed on exit.
+``host.gc`` span over each garbage collection and counts it, and counts
+the collections of the oldest generation (the full ones, which trace
+every tracked object) apart.  The hook is registered on call entry and
+removed on exit.
 
 This module reads no clock and keeps no state but the open record and the
 ring; it imports no ``repro`` module.
@@ -62,6 +64,7 @@ COUNTERS = (
     "placement.pinned",   # deadline-prior tasks pinned to fresh pairs
     "placement.fallback",  # tasks on a pair in use of a non-primary class
     "gc.collections",     # garbage collections during the call
+    "gc.full",            # among them, collections of the oldest generation
 )
 
 #: Calls the ring keeps (one int64 row of :data:`COUNTERS` each).
@@ -84,6 +87,7 @@ def spanned(name: str):
 
 _COL = {name: i for i, name in enumerate(COUNTERS)}
 _GC = _COL["gc.collections"]
+_GC_FULL = _COL["gc.full"]
 _open: list | None = None      # the open outermost call's counts
 _gc_span: TraceAnnotation | None = None
 _ring: np.ndarray | None = None
@@ -118,6 +122,8 @@ def _on_gc(phase: str, info: dict) -> None:
     if phase == "start":
         if _open is not None:
             _open[_GC] += 1
+            if info["generation"] == 2:
+                _open[_GC_FULL] += 1
         _gc_span = TraceAnnotation("host.gc")
         _gc_span.__enter__()
     elif _gc_span is not None:

@@ -81,7 +81,6 @@ See docs/EQUATIONS.md for the full equation/algorithm -> code map.
 
 from __future__ import annotations
 
-import dataclasses
 from typing import List, Optional
 
 import numpy as np
@@ -93,7 +92,8 @@ from repro.core.engine import ClusterEngine
 from repro.core.faults import FaultInjector, FaultTrace, make_degrade
 from repro.core.placement import PendingRow, PlacementContext
 from repro.core.scheduling import (chosen_feasibility, count_fallback,
-                                   count_violations, fill_readjusted)
+                                   count_violations, fill_readjusted,
+                                   run_energy)
 from repro.core.single_task import TaskConfig
 from repro.core.tasks import TaskSet
 from repro.kernels import layout
@@ -205,7 +205,7 @@ class _PipelineState:
         self.ivs = [mc.effective_interval(interval) for mc in mcs]
         self.tmin = self._floors_sync()
         # Full-horizon config columns, filled chunk by chunk.  f64 storage:
-        # every consumer (precompute casts, make_assignment floats, list
+        # every consumer (precompute casts, add_record floats, list
         # mirrors) upcasts the solver's f32 values anyway, and f32 -> f64 is
         # exact, so the scattered values read back bit-identically.
         self.cfgs = [TaskConfig(
@@ -287,7 +287,7 @@ class _ReadjustPrefetch:
         self.use_kernel = use_kernel
         self.dedup = dedup
         self.sent = 0
-        self.batches: list = []   # (assignment idx, windows, AsyncSolve)
+        self.batches: list = []   # (record rows, windows, AsyncSolve)
 
     def dispatch(self, pending: List[PendingRow]):
         """Send every pending row queued since the last call, one boundary
@@ -313,8 +313,7 @@ class _ReadjustPrefetch:
             self.batches.append((ai[m], windows[m], handle))
 
     @obs.spanned("schedule.records")
-    def flush_sync(self, assignments: List[cl.Assignment],
-                   pending: List[PendingRow]):
+    def flush_sync(self, records: cl.Records, pending: List[PendingRow]):
         """Dispatch the tail rows, block on every batch and write the DVFS
         fields back (the pipelined :func:`fill_readjusted`)."""
         self.dispatch(pending)
@@ -327,12 +326,7 @@ class _ReadjustPrefetch:
             p = rows[:, layout.SOL_P].astype(np.float64)
             feas = rows[:, layout.SOL_FEASIBLE] > 0.5
             t = np.where(feas, np.minimum(t, windows), t)  # snap f32 residual
-            e = p * t
-            for j, a_i in enumerate(ai.tolist()):
-                a = assignments[a_i]
-                assignments[a_i] = dataclasses.replace(
-                    a, v=float(v[j]), fc=float(fc[j]), fm=float(fm[j]),
-                    power=float(p[j]), energy=float(e[j]))
+            records.setting[:, ai] = (v, fc, fm, p, p * t)
         self.batches = []
 
 
@@ -451,11 +445,11 @@ def schedule_online(task_set: TaskSet, l: int = 1, theta: float = 1.0,
         order_cls = machines.class_order(cfgs)      # [C, n]
 
     eng = ClusterEngine(l, servers=True, rho=rho, classes=mcs)
-    assignments: List[cl.Assignment] = []
+    records = cl.Records(n)
     pending: List[PendingRow] = []
     ctx = PlacementContext(eng, cfgs, deadline, theta=theta,
                            readjust=(algorithm == "edl"),
-                           assignments=assignments, pending=pending,
+                           records=records, pending=pending,
                            order_cls=order_cls,
                            incremental=(pipeline and placement == "vector"))
 
@@ -477,7 +471,7 @@ def schedule_online(task_set: TaskSet, l: int = 1, theta: float = 1.0,
         order = None if prep is not None \
             else np.argsort(deadline[idx], kind="stable")
 
-        base = len(assignments)
+        base = records.n
         with obs.span("placement.group"):
             if algorithm == "bin" and slot == 0:
                 # Algorithm 6 offline phase: worst-fit on task utilization.
@@ -501,14 +495,14 @@ def schedule_online(task_set: TaskSet, l: int = 1, theta: float = 1.0,
         if injector is not None:
             injector.advance(np.inf)   # events after the last arrival slot
         # Materialize the in-flight readjustment batches + the tail rows.
-        readj.flush_sync(assignments, pending)
+        readj.flush_sync(records, pending)
     else:
         for slot, idx in groups:
             place_group(slot, idx)
         if injector is not None:
             injector.advance(np.inf)   # events after the last arrival slot
         # Deferred theta-readjustment solves: one batched dispatch per class.
-        fill_readjusted(assignments, pending, task_set, interval, use_kernel,
+        fill_readjusted(records, pending, task_set, interval, use_kernel,
                         mcs, dedup=dedup)
     if injector is not None:
         injector.finalize_records()    # re-price truncated records
@@ -519,11 +513,11 @@ def schedule_online(task_set: TaskSet, l: int = 1, theta: float = 1.0,
 
     with obs.span("schedule.account"):
         e_idle, e_overhead, n_servers = eng.finalize()
-        e_run = float(sum(a.energy for a in assignments))
+        e_run = run_energy(records)
         violations = count_violations(
-            assignments, deadline, chosen_feasibility(cfgs, assignments, n))
-        count_fallback(assignments, order_cls)
-        mk = max((a.finish for a in assignments), default=0.0)
+            records, deadline, chosen_feasibility(cfgs, records, n))
+        count_fallback(records, order_cls)
+        mk = float(records.finish[:records.n].max()) if records.n else 0.0
         e_bound = bounds.theoretical_bound(
             task_set, interval=interval, classes=mcs, l=l,
             rho=rho, dedup=dedup).e_bound if bound else 0.0
@@ -531,7 +525,7 @@ def schedule_online(task_set: TaskSet, l: int = 1, theta: float = 1.0,
             algorithm=f"online-{algorithm}{'+dvfs' if use_dvfs else ''}",
             e_run=e_run, e_idle=e_idle, e_overhead=e_overhead,
             n_pairs=eng.n_pairs, n_servers=n_servers,
-            violations=violations, assignments=assignments, makespan=mk,
+            violations=violations, records=records, makespan=mk,
             feasible_pairs=eng.feasible_pairs, e_bound=e_bound,
             fault_stats=dict(injector.stats) if injector is not None else None,
             cache_stats=cache_stats,

@@ -51,12 +51,14 @@ Three placement paths per context, all bit-identical by construction:
 
 The vectorized paths defer every engine write to one group commit
 (:meth:`~repro.core.engine.ClusterEngine.book_assignments` +
-:meth:`~repro.core.engine.ClusterEngine.sync_mu`) and gather the group's
-assignment records from the config columns in one shot; only fresh-server
-power-ons touch the engine live (they are DRS events).  θ-readjustment
-rows are *not* solved here — a readjusted task occupies exactly its
-window, so the rows are queued as :data:`PendingRow` and batch-priced
-after packing (:func:`repro.core.scheduling.fill_readjusted`).
+:meth:`~repro.core.engine.ClusterEngine.sync_mu`) and write the group's
+records into the call's column store (:class:`~repro.core.cluster.Records`)
+as slices, their settings gathered from the config columns in one shot; only
+fresh-server power-ons touch the engine live (they are DRS events).  The
+per-task rules append rows to the same store.  θ-readjustment rows are
+*not* solved here — a readjusted task occupies exactly its window, so the
+rows are queued as :data:`PendingRow` and batch-priced after packing
+(:func:`repro.core.scheduling.fill_readjusted`).
 
 See docs/ARCHITECTURE.md (placement subsystem layer) and docs/EQUATIONS.md
 for the full equation/algorithm -> code map.
@@ -97,26 +99,34 @@ _STREAM_OVERSHOOT = 8
 _SMALL_GROUP = 64
 _SMALL_GROUP_POOL = 8000
 
-#: pending θ-readjustment row: (assignment_index, task_index, window, class_id)
+#: pending θ-readjustment row: (record_row, task_index, window, class_id)
 PendingRow = Tuple[int, int, float, int]
 
 #: offline algorithm name -> pair-selection rule
 OFFLINE_RULES = {"edl": "wf", "edf-wf": "wf", "edf-bf": "bf", "lpt-ff": "ff"}
 
 
-def make_assignment(task: int, pair: int, start: float, cfg: TaskConfig,
-                    duration: Optional[float] = None,
-                    readjusted: bool = False, class_id: int = 0) -> cl.Assignment:
-    """An assignment at the task's configured setting; a readjusted one gets
-    its finish pinned to ``start + duration`` and its DVFS fields filled in
-    later by :func:`repro.core.scheduling.fill_readjusted`."""
+def add_record(records: cl.Records, task: int, pair: int, start: float,
+               cfg: TaskConfig, duration: Optional[float] = None,
+               readjusted: bool = False, class_id: int = 0) -> int:
+    """Append a record at the task's configured setting; a readjusted one
+    gets its finish pinned to ``start + duration`` and its DVFS fields
+    filled in later by :func:`repro.core.scheduling.fill_readjusted`."""
     t = cfg.t_hat[task] if duration is None else duration
-    return cl.Assignment(task=task, pair=pair, start=float(start),
-                         finish=float(start + t), v=float(cfg.v[task]),
-                         fc=float(cfg.fc[task]), fm=float(cfg.fm[task]),
-                         power=float(cfg.p_hat[task]),
-                         energy=float(cfg.e_hat[task]), readjusted=readjusted,
-                         class_id=class_id)
+    return records.append(task, pair, float(start), float(start + t),
+                          float(cfg.v[task]), float(cfg.fc[task]),
+                          float(cfg.fm[task]), float(cfg.p_hat[task]),
+                          float(cfg.e_hat[task]), readjusted, class_id)
+
+
+def _settings(cfg: TaskConfig, idx=slice(None)) -> np.ndarray:
+    """A class's record setting columns ``[v, fc, fm, p_hat, e_hat]`` of
+    the tasks ``idx``."""
+    return np.stack([np.asarray(cfg.v, np.float64)[idx],
+                     np.asarray(cfg.fc, np.float64)[idx],
+                     np.asarray(cfg.fm, np.float64)[idx],
+                     np.asarray(cfg.p_hat, np.float64)[idx],
+                     np.asarray(cfg.e_hat, np.float64)[idx]])
 
 
 def precompute(cfgs: Sequence[TaskConfig], order_cls: np.ndarray) -> dict:
@@ -125,19 +135,25 @@ def precompute(cfgs: Sequence[TaskConfig], order_cls: np.ndarray) -> dict:
     per-task floats ~20x faster off a list than off a numpy scalar)."""
     t_hat = [np.asarray(c.t_hat) for c in cfgs]
     t_min = [np.asarray(c.t_min) for c in cfgs]
+    n = t_hat[0].shape[0]
+    # Record setting columns of every class side by side, class c's task g
+    # at column c * n + g: one fancy index gathers a group's settings
+    # whatever its classes.  ``cols[c]`` is class c's view of the block.
+    setting = np.empty((len(cl.SETTING), len(cfgs) * n))
+    for c, cfg in enumerate(cfgs):
+        setting[:, c * n:(c + 1) * n] = _settings(cfg)
     return {
         "t_hat": t_hat,
         "t_min": t_min,
         "t_hat_l": [a.tolist() for a in t_hat],
         "t_min_l": [a.tolist() for a in t_min],
-        "order_cols": order_cls.T.tolist() if len(cfgs) > 1 else None,
-        # record columns [v, fc, fm, p_hat, e_hat] stacked per class: one
-        # fancy-index gathers a whole group's records
-        "cols": [np.stack([np.asarray(c.v, np.float64),
-                           np.asarray(c.fc, np.float64),
-                           np.asarray(c.fm, np.float64),
-                           np.asarray(c.p_hat, np.float64),
-                           np.asarray(c.e_hat, np.float64)]) for c in cfgs],
+        # a task's classes in preference order, as a tuple of ints (the
+        # collector stops tracking such tuples at its first pass)
+        "order_cols": list(zip(*order_cls.tolist())) if len(cfgs) > 1
+        else None,
+        "n": n,
+        "setting": setting,
+        "cols": [setting[:, c * n:(c + 1) * n] for c in range(len(cfgs))],
     }
 
 
@@ -473,8 +489,8 @@ class _GroupPools:
 class PlacementContext:
     """One scheduler run's placement state: the engine handle, the per-class
     Algorithm-1 configs and their precomputed column lookups, the policy
-    knobs (θ, readjustment on/off) and the output sinks (the assignment
-    list and the pending θ-readjustment rows).
+    knobs (θ, readjustment on/off) and the output sinks (the record store
+    and the pending θ-readjustment rows).
 
     ``readjust`` enables the EDL θ-readjustment on the worst-fit rule; it
     only takes effect for ``theta < 1`` (at ``θ = 1`` the readjustment
@@ -487,7 +503,7 @@ class PlacementContext:
     def __init__(self, eng: ClusterEngine, cfgs: Sequence[TaskConfig],
                  deadline: np.ndarray, *, theta: float = 1.0,
                  readjust: bool = False,
-                 assignments: Optional[List[cl.Assignment]] = None,
+                 records: Optional[cl.Records] = None,
                  pending: Optional[List[PendingRow]] = None,
                  order_cls: Optional[np.ndarray] = None,
                  incremental: bool = False):
@@ -496,7 +512,7 @@ class PlacementContext:
         self.deadline = np.asarray(deadline, dtype=np.float64)
         self.theta = float(theta)
         self.readjust = bool(readjust) and self.theta < 1.0
-        self.assignments = assignments if assignments is not None else []
+        self.records = records if records is not None else cl.Records()
         self.pending = pending if pending is not None else []
         self.order_cls = order_cls if order_cls is not None \
             else machines.class_order(self.cfgs)
@@ -529,7 +545,7 @@ class PlacementContext:
         filled in place (the pipelined config prefetch consumes Algorithm-1
         solutions chunk by chunk).  The numpy entries of ``pre`` alias the
         config arrays, so only the derived list mirrors and the stacked
-        record columns need resyncing — all mutated in place so aliases
+        setting columns need resyncing — all mutated in place so aliases
         held by a persistent pool stay live."""
         if self._pre is None:
             # First chunk: the plain build snapshots current (chunk-filled)
@@ -538,12 +554,7 @@ class PlacementContext:
             return
         pre = self._pre
         for c, cfg in enumerate(self.cfgs):
-            pre["cols"][c][:, idx] = np.stack(
-                [np.asarray(cfg.v, np.float64)[idx],
-                 np.asarray(cfg.fc, np.float64)[idx],
-                 np.asarray(cfg.fm, np.float64)[idx],
-                 np.asarray(cfg.p_hat, np.float64)[idx],
-                 np.asarray(cfg.e_hat, np.float64)[idx]])
+            pre["cols"][c][:, idx] = _settings(cfg, idx)
             th = np.asarray(cfg.t_hat)[idx].tolist()
             tm = np.asarray(cfg.t_min)[idx].tolist()
             th_l = pre["t_hat_l"][c]
@@ -556,7 +567,7 @@ class PlacementContext:
                     th_l[i] = th[j]
                     tm_l[i] = tm[j]
         if pre["order_cols"] is not None:
-            oc = self.order_cls[:, idx].T.tolist()
+            oc = list(zip(*self.order_cls[:, idx].tolist()))
             order_cols = pre["order_cols"]
             if isinstance(idx, slice):
                 order_cols[idx] = oc
@@ -598,7 +609,7 @@ class PlacementContext:
         """Commit one placed group to the engine in one shot (power-ons
         already wrote their pairs live; only assigned pairs moved, and for
         a pair assigned twice the chronologically last finish wins), then
-        gather the group's assignment records."""
+        write the group's records."""
         k = gidx.shape[0]
         self.eng.book_assignments(pid_col, start_col, dur_col)
         _, last = np.unique(pid_col[::-1], return_index=True)
@@ -609,23 +620,20 @@ class PlacementContext:
     def _gather(self, gidx: np.ndarray, pid_col: np.ndarray,
                 start_col: np.ndarray, dur_col: np.ndarray,
                 readj_col: np.ndarray, cls_col: np.ndarray):
-        """Bulk-build the group's assignment records from the config
-        columns (one fancy-index per class present)."""
+        """Write the group's records as slices of the record columns, the
+        settings gathered from the config columns by one fancy index."""
         pre = self.pre
-        k = gidx.shape[0]
-        if len(self.cfgs) == 1:
-            mat = pre["cols"][0][:, gidx]
-        else:
-            mat = np.empty((5, k))
-            for c in np.unique(cls_col):
-                m = cls_col == c
-                mat[:, m] = pre["cols"][int(c)][:, gidx[m]]
-        v_l, fc_l, fm_l, p_l, e_l = mat.tolist()
-        finish = start_col + dur_col
-        self.assignments.extend(map(
-            cl.Assignment, gidx.tolist(), pid_col.tolist(),
-            start_col.tolist(), finish.tolist(), v_l, fc_l, fm_l, p_l, e_l,
-            readj_col.tolist(), cls_col.tolist()))
+        rec = self.records
+        b = rec.add(gidx.shape[0])
+        e = rec.n
+        rec.task[b:e] = gidx
+        rec.pair[b:e] = pid_col
+        rec.class_id[b:e] = cls_col
+        rec.start[b:e] = start_col
+        np.add(start_col, dur_col, out=rec.finish[b:e])
+        rec.readjusted[b:e] = readj_col
+        rec.setting[:, b:e] = pre["setting"][
+            :, gidx if len(self.cfgs) == 1 else cls_col * pre["n"] + gidx]
 
     # -- placement paths -----------------------------------------------------
 
@@ -746,7 +754,7 @@ class PlacementContext:
         dur_col = t_hat.copy()
         cls_col = prim.astype(np.int64, copy=True)
         readj_col = np.zeros(k, dtype=bool)
-        base = len(self.assignments)
+        base = self.records.n
 
         gp = self._group_pools(t_now, pid_col, start_col, dur_col, cls_col)
         pool = gp.pool
@@ -1175,7 +1183,7 @@ class PlacementContext:
         order_cls = self.order_cls
         theta = self.theta
         readjust_on = self.readjust
-        assignments = self.assignments
+        records = self.records
         pending = self.pending
         obs.count("placement.scalar", len(order))
         for r in order:
@@ -1194,8 +1202,8 @@ class PlacementContext:
                     start = max(t_now, float(eng.mu[pid]))
                     if d - start >= t_hat - _EPS:
                         eng.assign(pid, start, t_hat)
-                        assignments.append(make_assignment(
-                            gidx, pid, start, cfg_c, class_id=c))
+                        add_record(records, gidx, pid, start, cfg_c,
+                                   class_id=c)
                         placed = True
                         break
                     elif readjust_on:
@@ -1204,11 +1212,10 @@ class PlacementContext:
                         window = d - start
                         if window >= t_theta - _EPS:
                             eng.assign(pid, start, window)
-                            pending.append((len(assignments), gidx, window,
-                                            c))
-                            assignments.append(make_assignment(
-                                gidx, pid, start, cfg_c, duration=window,
-                                readjusted=True, class_id=c))
+                            pending.append((records.n, gidx, window, c))
+                            add_record(records, gidx, pid, start, cfg_c,
+                                       duration=window, readjusted=True,
+                                       class_id=c)
                             placed = True
                             break
                 else:
@@ -1218,8 +1225,8 @@ class PlacementContext:
                     if pid >= 0:
                         start = max(t_now, float(eng.mu[pid]))
                         eng.assign(pid, start, t_hat)
-                        assignments.append(make_assignment(
-                            gidx, pid, start, cfg_c, class_id=c))
+                        add_record(records, gidx, pid, start, cfg_c,
+                                   class_id=c)
                         placed = True
                         break
             if not placed:
@@ -1228,8 +1235,7 @@ class PlacementContext:
                 pid = self.acquire_fresh(t_now, c)
                 start = max(t_now, float(eng.mu[pid]))
                 eng.assign(pid, start, float(cfg_c.t_hat[gidx]))
-                assignments.append(make_assignment(gidx, pid, start, cfg_c,
-                                                   class_id=c))
+                add_record(records, gidx, pid, start, cfg_c, class_id=c)
 
     @obs.spanned("placement.group")
     def place_orphans(self, tids: np.ndarray, t_now: float, rule: str,
@@ -1267,7 +1273,7 @@ class PlacementContext:
         eng = self.eng
         cfgs = self.cfgs
         deadline = self.deadline
-        assignments = self.assignments
+        records = self.records
         pending = self.pending
         n_degraded = 0
         order = np.argsort(deadline[tids], kind="stable")     # EDF
@@ -1286,16 +1292,16 @@ class PlacementContext:
                     window = d - start
                     if window >= t_hat - _EPS:
                         eng.assign(pid, start, t_hat)
-                        assignments.append(make_assignment(
-                            g, pid, start, cfg_c, class_id=c))
+                        add_record(records, g, pid, start, cfg_c,
+                                   class_id=c)
                         placed = True
                         break
                     if window >= float(cfg_c.t_min[g]) - _EPS:
                         eng.assign(pid, start, window)
-                        pending.append((len(assignments), g, window, c))
-                        assignments.append(make_assignment(
-                            g, pid, start, cfg_c, duration=window,
-                            readjusted=True, class_id=c))
+                        pending.append((records.n, g, window, c))
+                        add_record(records, g, pid, start, cfg_c,
+                                   duration=window, readjusted=True,
+                                   class_id=c)
                         placed = True
                         break
                 else:
@@ -1303,8 +1309,8 @@ class PlacementContext:
                     if pid >= 0:
                         start = max(t_now, float(eng.mu[pid]))
                         eng.assign(pid, start, t_hat)
-                        assignments.append(make_assignment(
-                            g, pid, start, cfg_c, class_id=c))
+                        add_record(records, g, pid, start, cfg_c,
+                                   class_id=c)
                         placed = True
                         break
             if placed:
@@ -1318,23 +1324,19 @@ class PlacementContext:
             if window < t_hat - _EPS:
                 if rule == "wf" and window >= float(cfg_c.t_min[g]) - _EPS:
                     eng.assign(pid, start, window)
-                    pending.append((len(assignments), g, window, c))
-                    assignments.append(make_assignment(
-                        g, pid, start, cfg_c, duration=window,
-                        readjusted=True, class_id=c))
+                    pending.append((records.n, g, window, c))
+                    add_record(records, g, pid, start, cfg_c,
+                               duration=window, readjusted=True, class_id=c)
                     continue
                 n_degraded += 1
                 if rule == "wf" and degrade is not None:
                     v, fc, fm, t_run, p = degrade(g, c)
                     eng.assign(pid, start, t_run)
-                    assignments.append(cl.Assignment(
-                        task=g, pair=pid, start=start, finish=start + t_run,
-                        v=v, fc=fc, fm=fm, power=p, energy=p * t_run,
-                        class_id=c))
+                    records.append(g, pid, start, start + t_run, v, fc, fm,
+                                   p, p * t_run, class_id=c)
                     continue
             eng.assign(pid, start, t_hat)
-            assignments.append(make_assignment(g, pid, start, cfg_c,
-                                               class_id=c))
+            add_record(records, g, pid, start, cfg_c, class_id=c)
         return int(tids.size), n_degraded
 
     def binpack_offline_util(self, idx, order, t_now: float):
@@ -1380,8 +1382,7 @@ class PlacementContext:
                     continue
                 eng.assign(pid, start, t_hat)
                 util[pid] += u_hat
-                self.assignments.append(make_assignment(gidx, pid, start,
-                                                        cfg_c, class_id=c))
+                add_record(self.records, gidx, pid, start, cfg_c, class_id=c)
                 placed = True
                 break
             if not placed:
@@ -1394,5 +1395,4 @@ class PlacementContext:
                 start = max(t_now, float(eng.mu[pid]))
                 eng.assign(pid, start, t_hat)
                 util[pid] += u_hat
-                self.assignments.append(make_assignment(gidx, pid, start,
-                                                        cfg_c, class_id=c))
+                add_record(self.records, gidx, pid, start, cfg_c, class_id=c)

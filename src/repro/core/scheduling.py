@@ -53,7 +53,6 @@ See docs/EQUATIONS.md for the full equation/algorithm -> code map.
 
 from __future__ import annotations
 
-import dataclasses
 from typing import List, Optional, Sequence
 
 import numpy as np
@@ -64,7 +63,7 @@ from repro.core.dvfs import ScalingInterval
 from repro.core.engine import ClusterEngine
 from repro.core.machines import MachineClass, resolve_classes
 from repro.core.placement import (OFFLINE_RULES, PendingRow, PlacementContext,
-                                  make_assignment)
+                                  add_record)
 from repro.core.single_task import TaskConfig
 from repro.core.tasks import TaskSet
 
@@ -103,16 +102,15 @@ def configure_all(task_set: TaskSet, use_dvfs: bool,
 
 
 @obs.spanned("schedule.records")
-def fill_readjusted(assignments: List[cl.Assignment],
-                    pending: List[PendingRow],
+def fill_readjusted(records: cl.Records, pending: List[PendingRow],
                     task_set: TaskSet, interval: ScalingInterval,
                     use_kernel: bool, mcs: Sequence[MachineClass],
                     dedup: bool = True):
     """Solve every deferred theta-readjustment in one batched dispatch per
-    class present and write the DVFS settings/energies back into the
-    assignment list.
+    class present and write the DVFS settings/energies into the record
+    columns.
 
-    ``pending`` rows are ``(assignment_index, task_index, window, class_id)``.
+    ``pending`` rows are ``(record_row, task_index, window, class_id)``.
     The schedule itself never depends on these solves — a readjusted task
     always occupies exactly its window — so they are batched after packing:
     one ``pallas_call`` (or one jitted boundary solve) per class instead of
@@ -126,14 +124,17 @@ def fill_readjusted(assignments: List[cl.Assignment],
     v, fc, fm, t, p, e = machines.readjust_classes(
         task_set.params, rows, windows, cids, mcs, interval, use_kernel,
         dedup=dedup)
-    for k, (ai, _, _, _) in enumerate(pending):
-        a = assignments[ai]
-        assignments[ai] = dataclasses.replace(
-            a, v=float(v[k]), fc=float(fc[k]), fm=float(fm[k]),
-            power=float(p[k]), energy=float(e[k]))
+    ai = np.asarray([r for r, _, _, _ in pending], dtype=np.int64)
+    records.setting[:, ai] = (v, fc, fm, p, e)
 
 
-def count_violations(assignments: List[cl.Assignment], deadline: np.ndarray,
+def live_rows(records: cl.Records) -> np.ndarray:
+    """Rows of the records not truncated by a server failure (every task
+    keeps exactly one)."""
+    return np.flatnonzero(~records.failed[:records.n])
+
+
+def count_violations(records: cl.Records, deadline: np.ndarray,
                      feasible: np.ndarray) -> int:
     """Each violated task counts exactly once: infeasible at configuration
     time (cannot meet its deadline at max speed) OR finished past its
@@ -141,49 +142,50 @@ def count_violations(assignments: List[cl.Assignment], deadline: np.ndarray,
     skipped: the task is judged by its re-placed record (every task keeps
     exactly one live record under fault injection)."""
     violated = ~np.asarray(feasible, dtype=bool)
-    if assignments:
-        t = np.fromiter((a.task for a in assignments if not a.failed),
-                        np.int64)
-        f = np.fromiter((a.finish for a in assignments if not a.failed),
-                        np.float64)
+    if records.n:
+        live = live_rows(records)
+        t = records.task[live]
+        f = records.finish[live]
         violated[t[f > deadline[t] + 1e-6]] = True
     return int(np.sum(violated))
 
 
-def chosen_feasibility(cfgs: Sequence[TaskConfig],
-                       assignments: List[cl.Assignment],
+def chosen_feasibility(cfgs: Sequence[TaskConfig], records: cl.Records,
                        n_tasks: int) -> np.ndarray:
     """Per-task feasibility on the class each task actually ran on (for a
     task re-placed after a server failure: the class of its live record —
     failed records are skipped)."""
     feas = np.ones(n_tasks, dtype=bool)
-    if not assignments:
+    if not records.n:
         return feas
-    t = np.fromiter((a.task for a in assignments if not a.failed), np.int64)
+    live = live_rows(records)
+    t = records.task[live]
     if len(cfgs) == 1:
         feas[t] = np.asarray(cfgs[0].feasible, bool)[t]
         return feas
-    cid = np.fromiter((a.class_id for a in assignments if not a.failed),
-                      np.int64)
+    cid = records.class_id[live]
     for c in np.unique(cid):
         tc = t[cid == c]
         feas[tc] = np.asarray(cfgs[int(c)].feasible, bool)[tc]
     return feas
 
 
-def count_fallback(assignments: List[cl.Assignment],
-                   order_cls: np.ndarray) -> None:
+def count_fallback(records: cl.Records, order_cls: np.ndarray) -> None:
     """Count the tasks whose live record lies on a class other than their
     primary one (``placement.fallback``).  Every rule opens a fresh pair
     of the task's primary class, so such a record is a pair in use that
     the task fell back to.  One class never counts."""
-    if order_cls.shape[0] < 2 or not assignments:
+    if order_cls.shape[0] < 2 or not records.n:
         return
-    t = np.fromiter((a.task for a in assignments if not a.failed), np.int64)
-    cid = np.fromiter((a.class_id for a in assignments if not a.failed),
-                      np.int64)
-    obs.count("placement.fallback",
-              int(np.count_nonzero(cid != order_cls[0][t])))
+    live = live_rows(records)
+    obs.count("placement.fallback", int(np.count_nonzero(
+        records.class_id[live] != order_cls[0][records.task[live]])))
+
+
+def run_energy(records: cl.Records) -> float:
+    """``E_run``: the records' energies summed in record order, one Python
+    float addition at a time."""
+    return float(sum(records.energy[:records.n].tolist()))
 
 
 @obs.call("schedule.offline")
@@ -234,12 +236,12 @@ def schedule_offline(task_set: TaskSet, l: int = 1, theta: float = 1.0,
     deadline = np.asarray(task_set.deadline, dtype=np.float64)
     order_cls = machines.class_order(cfgs)          # [C, n]
     primary = order_cls[0]
-    assignments: List[cl.Assignment] = []
+    records = cl.Records(n)
     pending: List[PendingRow] = []
     eng = ClusterEngine(l, servers=False, classes=mcs)
     ctx = PlacementContext(eng, cfgs, deadline, theta=theta,
                            readjust=(algorithm == "edl"),
-                           assignments=assignments, pending=pending,
+                           records=records, pending=pending,
                            order_cls=order_cls)
 
     # --- Phase 2a: tasks that are deadline-prior on their primary class,
@@ -258,8 +260,7 @@ def schedule_offline(task_set: TaskSet, l: int = 1, theta: float = 1.0,
             c = int(primary[t_idx])
             pid = eng.open_pair(class_id=c)
             eng.assign(pid, 0.0, float(cfgs[c].t_hat[t_idx]))
-            assignments.append(make_assignment(t_idx, pid, 0.0, cfgs[c],
-                                               class_id=c))
+            add_record(records, t_idx, pid, 0.0, cfgs[c], class_id=c)
 
     # --- Phase 2b: energy-prior tasks by the policy rule, trying classes in
     # min-energy-feasible-first order — ONE group at t=0 through the shared
@@ -285,18 +286,18 @@ def schedule_offline(task_set: TaskSet, l: int = 1, theta: float = 1.0,
             ctx.place_group_scalar(order, pos, 0.0, rule)
 
     # --- Deferred theta-readjustment solves: one batched dispatch per class.
-    fill_readjusted(assignments, pending, task_set, interval, use_kernel, mcs,
+    fill_readjusted(records, pending, task_set, interval, use_kernel, mcs,
                     dedup=dedup)
     # Solve-cache counters of the scheduling solves (not the e_bound solve).
     cache_stats = solver_cache.GLOBAL_CACHE.call_stats() if dedup else None
 
     # --- Phase 3: Algorithm 3 server grouping + Eq. (6) energies per class.
     with obs.span("schedule.account"):
-        e_run = float(sum(a.energy for a in assignments))
+        e_run = run_energy(records)
         e_idle, e_overhead, n_servers = eng.finalize()
         violations = count_violations(
-            assignments, deadline, chosen_feasibility(cfgs, assignments, n))
-        count_fallback(assignments, order_cls)
+            records, deadline, chosen_feasibility(cfgs, records, n))
+        count_fallback(records, order_cls)
         e_bound = bounds.theoretical_bound(
             task_set, interval=interval, classes=mcs,
             dedup=dedup).e_bound if bound else 0.0
@@ -304,7 +305,7 @@ def schedule_offline(task_set: TaskSet, l: int = 1, theta: float = 1.0,
             algorithm=f"{algorithm}{'+dvfs' if use_dvfs else ''}",
             e_run=e_run, e_idle=e_idle, e_overhead=e_overhead,
             n_pairs=eng.n_pairs, n_servers=n_servers, violations=violations,
-            assignments=assignments,
+            records=records,
             makespan=float(eng.mu.max()) if eng.n_pairs else 0.0,
             feasible_pairs=eng.feasible_pairs, e_bound=e_bound,
             cache_stats=cache_stats,

@@ -102,9 +102,12 @@ def run_loop(step_fn: Callable, state, data, cfg: LoopConfig, *,
             log(f"[loop] step {step}: FAILURE {type(e).__name__}: {e}; "
                 f"restoring latest checkpoint "
                 f"({recoveries}/{cfg.max_recoveries})")
-            if store.latest_step() is not None:
-                state = store.restore(state, shardings=state_shardings)
-                step = int(store.latest_step()) + 1
+            store.wait()    # a save in flight would publish mid-restore
+            last = store.latest_step()
+            if last is not None:
+                state = store.restore(state, step=last,
+                                      shardings=state_shardings)
+                step = int(last) + 1
             else:
                 step = start  # nothing saved yet: restart from scratch
 

@@ -103,14 +103,36 @@ def test_garbage_collections_open_spans_inside_a_call(tmp_path):
 
     r, spans = traced(collecting, tmp_path)
     assert r.counters["gc.collections"] >= 1
+    assert r.counters["gc.full"] >= 1
     assert sum(sp[0] == "host.gc" for sp in spans) >= 1
     assert_nested(spans, {"schedule.offline"})
     assert not any(getattr(cb, "__module__", None) == obs.__name__
                for cb in gc.callbacks)
 
 
+@pytest.mark.parametrize("generations,full", [((0,), 0), ((0, 1), 0),
+                                              ((0, 1, 2), 1), ((2, 2), 2)])
+def test_full_collections_are_counted_apart(generations, full):
+    """``gc.full`` counts the collections of the oldest generation only;
+    ``gc.collections`` counts every one."""
+    class Result:
+        counters = None
+
+    @obs.call("schedule.online")
+    def collecting():
+        for g in generations:
+            gc.collect(g)
+        return Result()
+
+    gc.collect()              # no automatic collection is due in the call
+    r = collecting()
+    assert r.counters["gc.full"] == full
+    assert r.counters["gc.collections"] >= len(generations)
+
+
 def pinned(counters):
-    return {k: v for k, v in counters.items() if k != "gc.collections"}
+    return {k: v for k, v in counters.items()
+            if k not in ("gc.collections", "gc.full")}
 
 
 def test_offline_counters_are_pinned():

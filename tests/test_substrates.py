@@ -179,6 +179,40 @@ def test_loop_failure_recovery(tmp_path):
     assert float(out["state"]) == pytest.approx(sum(range(10)))
 
 
+def test_loop_recovery_waits_for_the_save_in_flight(tmp_path, monkeypatch):
+    """A failure right after a checkpoint step: the save of that step is
+    still being written when the loop restores, and publishes while the
+    restore reads.  The loop must resume from the step it restored."""
+    import time as _t
+
+    from repro.checkpoint import store as store_mod
+    rename, load = os.rename, np.load
+
+    def slow_rename(a, b):
+        _t.sleep(0.05)
+        rename(a, b)
+
+    def slow_load(*a, **k):
+        _t.sleep(0.2)
+        return load(*a, **k)
+
+    monkeypatch.setattr(store_mod.os, "rename", slow_rename)
+    monkeypatch.setattr(store_mod.np, "load", slow_load)
+    fail_at = {"armed": True}
+
+    def failure_hook(step):
+        if step == 7 and fail_at["armed"]:
+            fail_at["armed"] = False
+            raise RuntimeError("simulated device loss")
+
+    cfg = LoopConfig(total_steps=10, checkpoint_every=2,
+                     checkpoint_dir=str(tmp_path / "ck"), log_every=0)
+    out = run_loop(toy_step, jnp.asarray(0.0), ToyData(), cfg,
+                   failure_hook=failure_hook, log=lambda *_: None)
+    assert out["recoveries"] == 1
+    assert float(out["state"]) == pytest.approx(sum(range(10)))
+
+
 def test_loop_straggler_watchdog():
     import time as _t
 
