@@ -12,7 +12,7 @@ t=0" case.  This harness
   ``scheduling.configure_all`` and injecting them via
   ``schedule_offline(cfgs=...)``;
 * compares the vectorized placement path (``placement="vector"``, the
-  default: batched worst-fit frontier, pooled probes, bulk fresh-pair
+  default: worst-fit heap finisher, pooled probes, bulk fresh-pair
   opens) against the per-task scalar reference loop
   (``placement="scalar"``) — bit-identical by construction, asserted to
   1e-9 rel (it actually matches exactly);

@@ -9,11 +9,11 @@ work per arrival group:
 * chunked per-arrival-group solve batches skip the serial path's
   sort-based ``np.unique`` pre-pass (the solve cache's probe already
   carries the cross-chunk dedup);
-* the chunk prologue (EDF orders, per-class ``t_hat`` gathers) is hoisted
-  into one vectorized ``PlacementContext.prepare_chunk`` pass;
-* persistent candidate pools replace the per-group frontier rebuild with
-  delta reconciliation (touched-pair merge, batched power-off deletion,
-  fault-epoch invalidation).
+* the chunk prologue (EDF orders, deadline gathers) is hoisted into one
+  vectorized ``PlacementContext.prepare_chunk`` pass;
+* persistent per-class pools replace the per-group pool rebuild with
+  delta reconciliation (batched power-off deletion, fault-epoch
+  invalidation).
 
 Timing method: both modes are fully warmed (jit compiles), then timed
 interleaved for ``--reps`` repeats with a cold solve cache and the GC

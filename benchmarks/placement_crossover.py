@@ -1,7 +1,7 @@
 """Per-group cost of the two worst-fit rules of
-``PlacementContext.place_group_vector``: the batched rounds against the
-per-task scalar rule, for groups of 1 to 256 tasks, over a warm
-incremental pool taken at the busiest slot of a real day.
+``PlacementContext.place_group_vector`` for a single-class group: the heap
+finisher against the per-task rule, for groups of 1 to 256 tasks, over a
+warm incremental pool taken at the busiest slot of a real day.
 
     PYTHONPATH=src python -m benchmarks.placement_crossover [--reps 41]
 
@@ -31,7 +31,7 @@ from repro.core import cluster as cl, machines, online, placement, tasks
 SIZES = (1, 2, 4, 8, 16, 24, 32, 48, 64, 128, 256)
 KW = dict(l=4, theta=0.9, bound=False)
 # values of placement._SMALL_GROUP that force each rule on every group
-RULES = (("batched", 0), ("scalar", 1 << 40))
+RULES = (("heap", 0), ("scalar", 1 << 40))
 
 
 def days(seed: int):
@@ -92,10 +92,7 @@ def sweep(name, ts, reps):
     run_day(ts, cfgs, lambda ctx, t: pool_at.__setitem__(
         t, ctx.eng.pool_ids(0).size))
     t_cut = max(pool_at, key=pool_at.get)
-    # Reach the slot as the batched rule does, merging the carried stream
-    # every group, so neither rule inherits the other's deferred work.
     got = []
-    placement._SMALL_GROUP = RULES[0][1]
     run_day(ts, cfgs, lambda ctx, t: got.append(snapshot(ctx))
             if t == t_cut and not got else None)
     slots = online.arrival_slots(ts)

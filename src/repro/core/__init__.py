@@ -11,10 +11,10 @@ Architecture (top to bottom)::
                         column; two thin drivers over one placement core)
         |
     placement           placement.PlacementContext - THE pair-selection
-                        subsystem (per-class compact pools, batched
-                        worst-fit frontier + theta-rows, pooled first/best
-                        fit, per-task reference loop; offline == the
-                        degenerate one-group-at-t=0 case)
+                        subsystem (per-class compact pools, per-task
+                        worst fit or a heap finisher + theta-rows, pooled
+                        first/best fit, per-task reference loop; offline ==
+                        the degenerate one-group-at-t=0 case)
         |
     machine classes     machines.MachineClass / REGISTRY - per-class task
                         constants + scaling box; configure_classes runs

@@ -59,7 +59,6 @@ COUNTERS = (
     "solve.evictions",    # cache rows evicted to make room
     "solve.sent",         # rows handed to the solver, padding included
     "solve.pad",          # padding rows among them
-    "placement.batched",  # tasks placed by batched prefix rounds
     "placement.scalar",   # tasks placed by a per-task rule
     "placement.pinned",   # deadline-prior tasks pinned to fresh pairs
     "placement.fallback",  # tasks on a pair in use of a non-primary class

@@ -30,12 +30,12 @@ worst-fit on utilization for the offline batch and first-fit for online
 arrivals, with no readjustment - the heuristic used by Liu et al. [41].
 
 This module is a thin *driver*: every pair-selection path — the per-class
-compact pools, the batched EDF-prefix placement with θ-readjustment rows,
-the pooled first-fit probes, the lazy-heap scalar finish and the per-task
-reference loop — lives in the shared placement subsystem
+compact pools, the per-task worst-fit rule and the heap finisher with
+θ-readjustment rows, the pooled first-fit probes and the per-task reference
+loop over the engine — lives in the shared placement subsystem
 (:class:`repro.core.placement.PlacementContext`), which also serves the
 offline batch scheduler.  ``placement="vector"`` (default) runs the
-batched paths, ``placement="scalar"`` the reference loop; both are
+pooled paths, ``placement="scalar"`` the reference loop; both are
 bit-identical (``tests/test_event_engine.py`` pins this on a mixed-class
 horizon, ``benchmarks/online_scale.py`` guards the speedup).
 
@@ -66,13 +66,14 @@ Algorithm-1 batch is dispatched (JAX async dispatch; the host never blocks
 on dispatch) before the host places chunk ``k``, and the deferred
 θ-readjustment boundary re-solves join the next in-flight batch at each
 chunk boundary instead of forcing a run-end sync.  The vector placement
-path additionally keeps its per-class candidate pools alive across arrival
-groups (``PlacementContext(incremental=True)``) with delta reconciliation.
+path additionally keeps its per-class pools alive across arrival groups
+(``PlacementContext(incremental=True)``) with delta reconciliation.
 Both halves are bit-identical to the synchronous path by construction: the
 f32 key matrix IS the solver input and every solver is row-independent, so
-chunked solves return the same bits as one monolithic batch, and the
-persistent pools are pinned against the per-group rebuild by the frontier
-invariant (see :mod:`repro.core.placement`).  ``pipeline=False`` runs the
+chunked solves return the same bits as one monolithic batch, and a carried
+pool holds the same pairs and ``mu`` as a per-group rebuild (power-offs are
+cut out, a fault transition drops it; see
+:class:`repro.core.placement._GroupPools`).  ``pipeline=False`` runs the
 reference path unchanged.  See docs/ARCHITECTURE.md (pipelined online
 scheduling) for the dataflow diagram and the invalidation rules.
 
@@ -394,7 +395,7 @@ def schedule_online(task_set: TaskSet, l: int = 1, theta: float = 1.0,
     first-fit online).  ``classes`` selects the machine-class mix (``None``
     = the homogeneous paper setup with the scalar ``p_idle``/``delta_on``;
     with a mix, idle power and turn-on overhead come from each class).
-    ``placement`` picks the group-batched array path (``"vector"``, default)
+    ``placement`` picks the pooled array path (``"vector"``, default)
     or the per-task reference loop (``"scalar"``); both produce bit-identical
     schedules.  ``cfgs`` injects precomputed :func:`online_configs` output
     (must match ``task_set``/``classes``/``use_dvfs``/``interval``).
@@ -412,7 +413,7 @@ def schedule_online(task_set: TaskSet, l: int = 1, theta: float = 1.0,
 
     ``pipeline=True`` (default) overlaps the DVFS solve batches with the
     host placement (async chunked config prefetch + deferred readjustment
-    batches joining the in-flight work + persistent candidate pools on the
+    batches joining the in-flight work + persistent per-class pools on the
     vector path) — bit-identical to ``pipeline=False``, the synchronous
     reference path (pinned by ``tests/test_pipeline.py``).
     """

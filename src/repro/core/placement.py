@@ -22,23 +22,18 @@ owns that machinery once, parameterized by a :class:`PlacementContext`:
 
 Three placement paths per context, all bit-identical by construction:
 
-* :meth:`PlacementContext.place_group_vector` — the batched worst-fit/SPT
-  path (Algorithm 2/5 EDL and the plain worst-fit policy).  Worst-fit is a
-  sequential min-extraction process, but it batches exactly under a
-  frontier invariant: in task order, the group's class-``c`` tasks land on
-  the smallest-``mu`` eligible pairs of class ``c`` *provided* each task
-  fits (at its optimal length, or via a θ-readjustment window, whose pair
-  ``mu`` is pinned to the task's deadline) and no already-assigned pair's
-  new ``mu`` drops back to (or ties) the worst-fit frontier.  Both
-  conditions are array ops over per-class *compact pools*
-  (:class:`_GroupPools`) of the engine's ``mu``/``class_id`` columns; the
-  batch rounds alternate with the scalar rule per collision, and a lazy
-  frontier heap finishes the group when batching stops paying for itself.
-  A group below a crossover size (``_SMALL_GROUP``, which falls as the
-  pool grows) skips the rounds and takes the scalar rule task by task
-  over the same pools: there a round's fixed cost (candidate stream,
-  carried-stream merge, some 25 array ops) exceeds ``k`` argmins over
-  the pool.  A paper day's arrival groups hold ~3 tasks.
+* :meth:`PlacementContext.place_group_vector` — the worst-fit/SPT path
+  (Algorithm 2/5 EDL and the plain worst-fit policy) over per-class
+  *compact pools* (:class:`_GroupPools`) of the engine's ``mu``/``class_id``
+  columns, by one of two rules.  The per-task rule takes an argmin over the
+  class pool for each task; it places every multi-class group (class
+  fallback is per task) and every single-class group below a crossover
+  size (``_SMALL_GROUP``, which falls as the pool grows; a paper day's
+  arrival groups hold ~3 tasks).  A larger single-class group takes a heap
+  finisher keyed ``(mu, pair id)``, whose top is the argmin: offline the
+  heap holds the whole pool and fresh pairs open in bulk after the loop;
+  online it is seeded with the group's ``k`` smallest pool entries and
+  each fresh power-on pushes its pairs.
 * :meth:`PlacementContext.place_group_select` — the pooled first-fit
   (``"ff"``) / best-fit (``"bf"``) path (offline ``lpt-ff``/``edf-bf`` and
   the online Algorithm-6 first-fit), per-task probes vectorized over the
@@ -77,25 +72,19 @@ from repro.core.single_task import TaskConfig
 
 _EPS = 1e-9
 
-# Persistent candidate streams are built/kept this many times deeper than
-# the current group's ask: deep enough that consecutive groups rarely
-# exhaust the carried frontier (a rebuild is an argpartition over the whole
-# pool), shallow enough that the per-group touched-merge stays O(stream).
-# Result-neutral: streams are a coverage window over the same (mu, pair id)
-# order, and every consumer re-slices ``[:need]``.
-_STREAM_OVERSHOOT = 8
-
 # The crossover between the two worst-fit rules of
-# PlacementContext.place_group_vector.  A batched round costs about the
-# same whatever the group's size; the per-task scalar rule costs one argmin
-# over the class pool per task, dearer as the pool grows.  So a group of k
-# tasks over a pool of n pairs (its first task's class) takes the scalar
-# rule while k * (1 + n / _SMALL_GROUP_POOL) < _SMALL_GROUP: _SMALL_GROUP
-# is the crossover on an empty pool, and it halves at _SMALL_GROUP_POOL
-# pairs.
-# On a TPU v5e host the scalar rule won up to 48 tasks over 712 pairs and
-# up to 16 over 13,864 (benchmarks/placement_crossover.py); the rule puts
-# the crossover at 58.8 and 23.4.
+# PlacementContext.place_group_vector for a single-class group: the
+# per-task rule costs one argmin over the class pool per task, dearer as
+# the pool grows; the heap finisher costs a seed build over the pool per
+# group and a heap step per task.  A group of k tasks over a pool of n
+# pairs takes the per-task rule while k * (1 + n / _SMALL_GROUP_POOL) <
+# _SMALL_GROUP: _SMALL_GROUP is the crossover on an empty pool, and it
+# halves at _SMALL_GROUP_POOL pairs.
+# The values come from a TPU v5e host measurement that compared the
+# per-task rule with the batched prefix rounds that then sat on the other
+# side (benchmarks/placement_crossover.py): the per-task rule won up to 48
+# tasks over 712 pairs and up to 16 over 13,864; the rule puts the
+# crossover at 58.8 and 23.4.
 _SMALL_GROUP = 64
 _SMALL_GROUP_POOL = 8000
 
@@ -131,7 +120,7 @@ def _settings(cfg: TaskConfig, idx=slice(None)) -> np.ndarray:
 
 def precompute(cfgs: Sequence[TaskConfig], order_cls: np.ndarray) -> dict:
     """Per-run lookups for the vectorized paths: config columns as numpy
-    arrays (batch gathers) and as plain lists (the scalar-finish loop reads
+    arrays (batch gathers) and as plain lists (the per-task loops read
     per-task floats ~20x faster off a list than off a numpy scalar)."""
     t_hat = [np.asarray(c.t_hat) for c in cfgs]
     t_min = [np.asarray(c.t_min) for c in cfgs]
@@ -144,7 +133,6 @@ def precompute(cfgs: Sequence[TaskConfig], order_cls: np.ndarray) -> dict:
         setting[:, c * n:(c + 1) * n] = _settings(cfg)
     return {
         "t_hat": t_hat,
-        "t_min": t_min,
         "t_hat_l": [a.tolist() for a in t_hat],
         "t_min_l": [a.tolist() for a in t_min],
         # a task's classes in preference order, as a tuple of ints (the
@@ -162,68 +150,47 @@ class _GroupPools:
     mode, carried across every call of a run.
 
     A pool is the pair-id-ascending snapshot of the eligible pairs of one
-    class, kept in sync for the rest of the call while the engine itself is
-    only written at the group commit.  Its candidate stream is the
-    ``(mu, pair id)``-sorted frontier computed once per call (stale entries
-    drop out by exact ``mu`` comparison, a power-on appends its fresh
-    pairs), and ``min_new`` tracks the smallest already-assigned finish
-    time so a frontier re-entry is detected across batch rounds.
+    class as ``[ids, mus, n]``, kept in sync for the rest of the call while
+    the engine itself is only written at the group commit.
 
     **Persistent mode** (``PlacementContext(incremental=True)``, the
-    pipelined online path): pools and candidate streams survive from one
-    arrival group to the next under three delta rules instead of the
-    per-group full rebuild —
+    pipelined online path): pools survive from one arrival group to the
+    next instead of a per-group rebuild.  The placement paths write every
+    ``mu`` they move into the pool and :meth:`acquire` splices a power-on's
+    pairs in, so between groups only two things can leave a pool stale —
 
-    * *touched re-entry*: every pair whose ``mu`` moved (assignment, fresh
-      power-on splice) is recorded by **pair id** (ids are stable under the
-      position shifts that splices/deletions cause); at the next group
-      the touched set is merged back into the stream at its current ``mu``.
-      ``thresh`` records the stream's ``(mu, pair id)`` coverage bound from
-      build time: every pool entry outside the stream compares strictly
-      greater, assignments only *raise* ``mu``, so merged entries above the
-      bound can be dropped and the stream stays the true global frontier.
     * *power-off deletion*: servers the engine's DRS settle powered off
       (``ClusterEngine.drain_offs``) have their contiguous pair block cut
-      out of the pool; stream positions shift left.
+      out of the pool;
     * *epoch invalidation*: any fault transition (``fail_pairs`` /
       ``revive_pairs`` bump ``ClusterEngine.pool_epoch``) mutates pairs
       behind the pool's back — eligibility masks, mu truncations, orphan
-      re-placements — so everything is dropped and lazily rebuilt from the
+      re-placements — so every pool is dropped and lazily rebuilt from the
       live engine.  Failures are rare events; correctness over cleverness.
     """
 
-    __slots__ = ("ctx", "eng", "t_now", "grain", "t_hat_l", "pools", "cands",
-                 "fresh", "min_new", "pid_col", "start_col", "dur_col",
-                 "cls_col", "persistent", "touched", "thresh", "needs_merge",
-                 "epoch")
+    __slots__ = ("eng", "t_now", "grain", "t_hat_l", "pools", "pid_col",
+                 "start_col", "dur_col", "cls_col", "epoch")
 
     def __init__(self, ctx: "PlacementContext", t_now: float,
                  pid_col: np.ndarray, start_col: np.ndarray,
                  dur_col: np.ndarray, cls_col: np.ndarray):
-        self.ctx = ctx
         self.eng = ctx.eng
         self.t_now = t_now
         self.grain = ctx.grain
         self.t_hat_l = ctx.pre["t_hat_l"]
         self.pools: Dict[int, list] = {}
-        self.cands: Dict[int, list] = {}
-        self.fresh: Dict[int, list] = {}
-        self.min_new: Dict[int, float] = {}
         self.pid_col = pid_col
         self.start_col = start_col
         self.dur_col = dur_col
         self.cls_col = cls_col
-        self.persistent = False
-        self.touched: Dict[int, list] = {}
-        self.thresh: Dict[int, Optional[tuple]] = {}
-        self.needs_merge: set = set()
         self.epoch = 0
 
     def begin_group(self, t_now: float, pid_col: np.ndarray,
                     start_col: np.ndarray, dur_col: np.ndarray,
                     cls_col: np.ndarray):
         """Rebind the per-group output columns and reconcile the carried
-        pool state with everything the engine did since the last group."""
+        pools with everything the engine did since the last group."""
         self.t_now = t_now
         self.pid_col = pid_col
         self.start_col = start_col
@@ -233,42 +200,20 @@ class _GroupPools:
         if eng.pool_epoch != self.epoch:
             self.epoch = eng.pool_epoch
             self.pools.clear()
-            self.cands.clear()
-            self.fresh.clear()
-            self.min_new.clear()
-            self.touched.clear()
-            self.thresh.clear()
-            self.needs_merge.clear()
             eng.drain_offs()
             return
-        # Unconsumed fresh splices may sit below any stream bound: convert
-        # them (by id) into touched entries for the merge.  Must happen
-        # BEFORE power-off deletions shift pool positions.
-        for c, fl in self.fresh.items():
-            if fl:
-                ids = self.pools[c][0]
-                self.touched.setdefault(c, []).append(
-                    ids[np.asarray(fl, dtype=np.int64)])
-                self.fresh[c] = []
         offs = eng.drain_offs()
         if offs:
             self.apply_offs(offs)
-        for c in self.min_new:
-            self.min_new[c] = np.inf
-        self.needs_merge = set(self.cands)
-        for c in self.touched:
-            if c not in self.cands:
-                # No stream to reconcile against; a later build is full.
-                self.touched[c] = []
 
     def apply_offs(self, sids):
         """Cut the powered-off servers' contiguous pair blocks out of their
-        class pools (and shift/drop stream positions accordingly).  A
-        powered-on server always has its whole ``grain`` block in the pool,
-        so the whole batch is one keep-mask compaction per class — a
-        per-``sid`` slice shift is O(offs * pool) and collapses on diurnal
-        traces, where a falling edge powers off thousands of servers at
-        once.  Order-preserving, so it commutes with the loop form."""
+        class pools.  A powered-on server always has its whole ``grain``
+        block in the pool, so the whole batch is one keep-mask compaction
+        per class — a per-``sid`` slice shift is O(offs * pool) and
+        collapses on diurnal traces, where a falling edge powers off
+        thousands of servers at once.  Order-preserving, so it commutes
+        with the loop form."""
         grain = self.grain
         eng = self.eng
         multi = len(eng.classes) > 1
@@ -293,58 +238,13 @@ class _GroupPools:
             diff = np.zeros(n + 1, dtype=np.int64)
             np.add.at(diff, lo, 1)
             np.add.at(diff, hi, -1)
-            dead = np.cumsum(diff[:n]) > 0
-            keep = ~dead
+            keep = np.cumsum(diff[:n]) == 0
             m = int(keep.sum())
             if m == n:
                 continue
-            shift = np.cumsum(dead) - dead    # deleted positions before p
             ids[:m] = live[keep]
             mus[:m] = mus[:n][keep]
             st[2] = m
-            cst = self.cands.get(c)
-            if cst is not None:
-                cp, cm = cst
-                km = keep[cp]
-                if not km.all():
-                    cp, cm = cp[km], cm[km]
-                self.cands[c] = [cp - shift[cp], cm]
-
-    def _merge_carry(self, c: int):
-        """Fold the touched pair ids back into class ``c``'s carried stream
-        at their current ``mu`` (dropping entries beyond the coverage
-        bound and pairs that left the pool), keeping ``(mu, pair id)``
-        order — position order == id order inside a pool."""
-        ids, mus, n = self.pools[c]
-        cp, cm = self.cands[c]
-        alive = mus[cp] == cm
-        if not alive.all():
-            cp, cm = cp[alive], cm[alive]
-        tl = self.touched.get(c)
-        if tl:
-            tids = np.unique(np.concatenate(
-                [np.atleast_1d(np.asarray(x, dtype=np.int64)) for x in tl]))
-            self.touched[c] = []
-            pos = np.searchsorted(ids[:n], tids)
-            ok = pos < n
-            pos = np.where(ok, pos, 0)
-            ok &= ids[pos] == tids
-            pos = pos[ok]
-            if pos.size:
-                tmu = mus[pos]
-                th = self.thresh.get(c)
-                if th is not None:
-                    t_mu, t_pid = th
-                    keep = (tmu < t_mu) | ((tmu == t_mu)
-                                           & (ids[pos] <= t_pid))
-                    pos, tmu = pos[keep], tmu[keep]
-                if pos.size:
-                    allp = np.concatenate([cp, pos])
-                    allm = np.concatenate([cm, tmu])
-                    o = np.lexsort((allp, allm))
-                    cp, cm = allp[o], allm[o]
-        st = self.cands[c] = [cp, cm]
-        return st
 
     def pool(self, c: int):
         """Compact (pair-id ascending) snapshot of the eligible pairs of
@@ -359,89 +259,22 @@ class _GroupPools:
                                   self.eng.mu[ids].astype(np.float64,
                                                           copy=True),
                                   ids.size]
-            self.min_new[c] = np.inf
         return st
-
-    def candidates(self, c: int, need: int):
-        """Up to ``need`` live frontier entries of class c as (positions,
-        recorded mus), ordered by ``(mu, pair id)``."""
-        ids, mus, n = self.pool(c)
-        st = self.cands.get(c)
-        clean = False   # stream (re)built/merged this call -> fully alive
-        if st is not None and c in self.needs_merge:
-            self.needs_merge.discard(c)
-            st = self._merge_carry(c)
-            clean = True
-            if st[0].size < need and self.thresh.get(c) is not None:
-                # Carried stream exhausted below the ask while entries past
-                # its coverage bound exist: refresh with a full build.
-                st = None
-                del self.cands[c]
-            elif st[0].size > max(_STREAM_OVERSHOOT * need, 64):
-                # Keep the carried stream bounded: drop the sorted tail and
-                # *tighten* the coverage bound to the last kept entry (the
-                # dropped entries all compare greater, and any future mu
-                # move re-enters through the touched set) — without this a
-                # full-coverage stream (thresh None) re-absorbs every
-                # touched pair forever and the per-group merge degenerates
-                # into maintaining a whole sorted pool.
-                keep = max(_STREAM_OVERSHOOT * need, 64)
-                cp, cm = st[0][:keep], st[1][:keep]
-                self.thresh[c] = (float(cm[-1]), int(ids[cp[-1]]))
-                st = self.cands[c] = [cp, cm]
-        if st is None:
-            # Persistent mode overshoots the ask: a stream of exactly
-            # ``need`` entries is fully consumed by its own group, which
-            # would force a rebuild every group and make the carry pure
-            # overhead.  The extra entries are the same frontier, just
-            # deeper — the return below still slices [:need].
-            kc = min(max(_STREAM_OVERSHOOT * need, 64)
-                     if self.persistent else need, n)
-            m_live = mus[:n]
-            if kc and kc < n:
-                part = np.argpartition(m_live, kc - 1)[:kc]
-                cp = np.flatnonzero(m_live <= m_live[part].max())
-                cp = cp[np.lexsort((cp, m_live[cp]))][:kc]
-            else:
-                cp = np.argsort(m_live, kind="stable")
-            st = self.cands[c] = [cp, m_live[cp].copy()]
-            clean = True
-            if self.persistent:
-                self.thresh[c] = None if cp.size >= n else \
-                    (float(st[1][-1]), int(ids[cp[-1]]))
-                self.touched[c] = []
-                self.fresh.pop(c, None)
-        cp, cm = st
-        if not clean:
-            alive = self.pools[c][1][cp] == cm    # assigned entries drop out
-            if not alive.all():
-                cp, cm = cp[alive], cm[alive]
-                self.cands[c] = [cp, cm]
-        fr = self.fresh.get(c)
-        if fr:
-            fa = np.sort(np.asarray(fr, dtype=np.int64))
-            fa = fa[self.pools[c][1][fa] == self.t_now]  # consumed drop out
-            if fa.size:
-                allp = np.concatenate([cp, fa])
-                allm = np.concatenate([cm, np.full(fa.size, self.t_now)])
-                o = np.lexsort((allp, allm))      # position order == id order
-                return allp[o][:need], allm[o][:need]
-        return cp[:need], cm[:need]
 
     def acquire(self, i: int, g: int, c: int):
         """Fresh-pair fallback: open a fresh pair of class ``c`` (offline a
         standalone pair, online a DRS power-on of ``grain = l`` pairs — a
         live engine event), splice the new pairs into the class pool, and
-        assign the first one to task ``g`` at position ``i``."""
+        assign the first one to task ``g`` at position ``i``.  Returns the
+        first new pair's pool position and whether it was inserted
+        mid-pool (positions from there on shifted by ``grain``)."""
         t_now = self.t_now
         grain = self.grain
         eng = self.eng
         # Snapshot the pool BEFORE firing the engine event: pool_ids reads
         # live engine state, so a pool built after the power-on would
         # already contain the fresh pairs the splice below adds.
-        st = self.pools.get(c)
-        if st is None:
-            st = self.pool(c)
+        st = self.pool(c)
         pid = eng.acquire_pair(t_now, class_id=c) if eng.server_mode \
             else eng.open_pair(class_id=c)
         ids, mus, n = st
@@ -454,31 +287,19 @@ class _GroupPools:
                 st[1] = mus = np.concatenate(
                     [mus, np.empty(grow - mus.shape[0])])
         else:
-            # waking a lower-id server inserts mid-pool: shift the stored
-            # candidate/fresh positions past the insertion point.
+            # waking a lower-id server inserts mid-pool
             pos = int(np.searchsorted(ids[:n], pid))
             st[0] = ids = np.insert(ids[:n], pos,
                                     np.zeros(grain, dtype=np.int64))
             st[1] = mus = np.insert(mus[:n], pos, np.zeros(grain))
-            if c in self.cands:
-                cp, cm = self.cands[c]
-                self.cands[c] = [np.where(cp >= pos, cp + grain, cp), cm]
-            if self.fresh.get(c):
-                self.fresh[c] = [p + grain if p >= pos else p
-                                 for p in self.fresh[c]]
         th = self.t_hat_l[c][g]
         if grain == 1:                            # offline: one standalone pair
             ids[pos] = pid
         else:
             ids[pos: pos + grain] = pid + np.arange(grain)
             mus[pos + 1: pos + grain] = t_now
-            self.fresh.setdefault(c, []).extend(range(pos + 1, pos + grain))
         st[2] = n + grain
         mus[pos] = t_now + th                     # a fresh pair is free *now*
-        if self.persistent:
-            self.touched.setdefault(c, []).append(pid)
-        if self.min_new[c] > t_now + th:
-            self.min_new[c] = t_now + th
         self.pid_col[i] = pid
         self.start_col[i] = t_now
         self.dur_col[i] = th
@@ -519,10 +340,9 @@ class PlacementContext:
         self.primary = self.order_cls[0]
         self.grain = eng.l if eng.server_mode else 1
         self._pre = None
-        # Incremental mode (the pipelined online scheduler): pools and
-        # candidate streams persist across groups with delta reconciliation
-        # instead of a per-group rebuild; the engine logs power-offs for the
-        # deletion deltas.
+        # Incremental mode (the pipelined online scheduler): pools persist
+        # across groups with delta reconciliation instead of a per-group
+        # rebuild; the engine logs power-offs for the deletion deltas.
         self.incremental = bool(incremental)
         self._gp: Optional[_GroupPools] = None
         if self.incremental:
@@ -587,7 +407,6 @@ class PlacementContext:
         if gp is None:
             gp = self._gp = _GroupPools(self, t_now, pid_col, start_col,
                                         dur_col, cls_col)
-            gp.persistent = True
             gp.epoch = self.eng.pool_epoch
             self.eng.drain_offs()   # nothing existed to reconcile yet
         else:
@@ -667,8 +486,8 @@ class PlacementContext:
         permutations — the group id is the primary key and lexsort keeps
         arrival order on deadline ties, exactly like the per-group
         ``kind="stable"`` argsort), and the task-column gathers vectorize
-        across the whole chunk.  Returns one ``(gidx, prim, d, t_hat)``
-        tuple per group, each bit-identical to the inline prologue."""
+        across the whole chunk.  Returns one ``(gidx, prim, d)`` tuple per
+        group, each bit-identical to the inline prologue."""
         sizes = [idx.shape[0] for _, idx in groups]
         cat = np.concatenate([idx for _, idx in groups])
         gid = np.repeat(np.arange(len(sizes)), sizes)
@@ -677,174 +496,71 @@ class PlacementContext:
         gidx = cat[order]
         d_s = d_cat[order]
         prim = self.primary[gidx]
-        pre = self.pre
-        if len(self.cfgs) == 1:
-            t_hat = pre["t_hat"][0][gidx]
-        else:
-            t_hat = np.empty(gidx.shape[0])
-            for c in np.unique(prim):
-                m = prim == c
-                t_hat[m] = pre["t_hat"][int(c)][gidx[m]]
         out = []
         off = 0
         for s in sizes:
             sl = slice(off, off + s)
-            out.append((gidx[sl], prim[sl], d_s[sl], t_hat[sl]))
+            out.append((gidx[sl], prim[sl], d_s[sl]))
             off += s
         return out
 
     def place_group_vector(self, idx, order, t_now: float, prep=None):
-        """Batched worst-fit/SPT (+ θ-readjustment) placement for one
-        ordered group — Algorithm 2/5's pair rule.
+        """Worst-fit/SPT (+ θ-readjustment) placement for one ordered group
+        — Algorithm 2/5's pair rule — by one of two rules over the pools.
 
-        The placement loop alternates: batch the longest provable prefix
-        (see the frontier invariant in the module docstring), then place
-        the single violating task through the scalar rule — class fallback,
-        readjustment that does not batch, fresh-pair power-on, an exact
-        ``mu`` tie — and resume batching while a round nets enough tasks to
-        pay for itself; otherwise (power-on ramp, saturated frontier) the
-        rest of the group runs the same scalar rule as a tight loop over
-        the pools with a lazy frontier heap.  A group of ``k`` tasks over
-        a pool of ``n`` pairs with ``k * (1 + n / _SMALL_GROUP_POOL) <
-        _SMALL_GROUP`` takes that scalar rule for every task instead: a
-        batched round costs about the same at any ``k``, ``k`` argmins
-        less below the crossover (~59 tasks over a paper day's ~700
-        pairs, ~23 over 14k; see ``_SMALL_GROUP``).
-        Bit-identical to :meth:`place_group_scalar` (rule ``"wf"``) by
-        construction.
+        A multi-class group, or a single-class group of ``k`` tasks over a
+        pool of ``n`` pairs with ``k * (1 + n / _SMALL_GROUP_POOL) <
+        _SMALL_GROUP``, takes the per-task rule: an argmin over the class
+        pool for each task, with class fallback, θ-readjustment and the
+        fresh-pair power-on.  A larger single-class group takes a heap
+        finisher, which costs a heap step per task instead of an argmin
+        over the pool (~59 tasks over a paper day's ~700 pairs, ~23 over
+        14k; see ``_SMALL_GROUP``).  Bit-identical to
+        :meth:`place_group_scalar` (rule ``"wf"``) by construction.
 
         ``prep`` injects the group's :meth:`prepare_chunk` tuple; ``idx``
         and ``order`` are ignored then (the tuple already IS the ordered
         group).
         """
         if prep is not None:
-            gidx, prim, d, t_hat = prep
+            gidx, prim, d = prep
             k = gidx.shape[0]
             if k == 0:
                 return
-            pre = self.pre
         else:
             k = order.shape[0]
             if k == 0:
                 return
-            pre = self.pre
-            gidx = np.asarray(idx)[order]         # [k] task ids, batch order
+            gidx = np.asarray(idx)[order]         # [k] task ids, EDF order
             prim = self.primary[gidx]             # [k] primary class per task
             d = self.deadline[gidx]
+        pre = self.pre
         theta = self.theta
         readjust_on = self.readjust
         pending = self.pending
-        t_hat_cls = pre["t_hat"]
-        t_min_cls = pre["t_min"]
         t_hat_l = pre["t_hat_l"]
         t_min_l = pre["t_min_l"]
         order_cols = pre["order_cols"]
         grain = self.grain
 
-        # Per-group record columns, filled by the batch rounds and the
-        # scalar violators; records and engine state are committed once at
-        # the end.
-        if prep is None:
-            t_hat = np.empty(k)
-            for c in np.unique(prim):
-                m = prim == c
-                t_hat[m] = t_hat_cls[int(c)][gidx[m]]
+        # Per-group record columns, filled by the rule; records and engine
+        # state are committed once at the end.
         pid_col = np.empty(k, dtype=np.int64)
         start_col = np.empty(k)
-        dur_col = t_hat.copy()
+        dur_col = np.empty(k)
         cls_col = prim.astype(np.int64, copy=True)
         readj_col = np.zeros(k, dtype=bool)
         base = self.records.n
 
         gp = self._group_pools(t_now, pid_col, start_col, dur_col, cls_col)
         pool = gp.pool
-        candidates = gp.candidates
-        pools = gp.pools
-        fresh = gp.fresh
-        min_new = gp.min_new
-        persistent = gp.persistent
-        touched = gp.touched
-
-        valid = np.empty(k, dtype=bool)
-        pos_sel = np.empty(k, dtype=np.int64)
-
-        def batch_round(pos0: int) -> int:
-            """Batch the longest provable prefix of tasks[pos0:]; returns
-            the number of positions consumed."""
-            valid[pos0:] = False
-            if order_cols is None:                # single class: no split
-                by_class = ((0, np.arange(pos0, k)),)
-            else:
-                sub = prim[pos0:]
-                by_class = tuple((int(c), pos0 + np.flatnonzero(sub == c))
-                                 for c in np.unique(sub))
-            for c, tm in by_class:
-                cp, cm = candidates(int(c), tm.size)
-                kc = cp.size
-                if not kc:
-                    continue
-                w = t_hat[tm[:kc]]
-                start = np.maximum(t_now, cm)
-                window = d[tm[:kc]] - start
-                fit = window >= w - _EPS          # fits at optimal length
-                if readjust_on:
-                    # The θ-readjustment batches under the same frontier
-                    # check: the task occupies exactly its window, so its
-                    # pair's new mu is pinned to the task's deadline.
-                    t_min_c = t_min_cls[int(c)][gidx[tm[:kc]]]
-                    readj = ~fit & (window >= np.maximum(theta * w, t_min_c)
-                                    - _EPS)
-                else:
-                    readj = np.zeros(kc, dtype=bool)
-                dur = np.where(fit, w, window)
-                ok = fit | readj
-                # no-collision: every already-assigned pair's new mu
-                # (previous rounds and this one) stays strictly above the
-                # next candidate (ties -> scalar fallback).
-                pm = np.minimum.accumulate(start + dur)
-                ok &= np.concatenate(([min_new[int(c)]],
-                                      np.minimum(pm[:-1],
-                                                 min_new[int(c)]))) > cm
-                nvalid = kc if ok.all() else int(np.argmin(ok))
-                if nvalid:
-                    sel = tm[:nvalid]
-                    valid[sel] = True
-                    pos_sel[sel] = cp[:nvalid]
-                    start_col[sel] = start[:nvalid]
-                    dur_col[sel] = dur[:nvalid]
-                    readj_col[sel] = readj[:nvalid]
-            cut = k if valid[pos0:].all() \
-                else pos0 + int(np.argmin(valid[pos0:]))
-            if cut == pos0:
-                return 0
-            if order_cols is None:
-                by_class = ((0, np.arange(pos0, cut)),)
-            else:
-                sub = prim[pos0:cut]
-                by_class = tuple((int(c), pos0 + np.flatnonzero(sub == c))
-                                 for c in np.unique(sub))
-            for c, m in by_class:
-                ids, mus, _ = pools[int(c)]
-                pos = pos_sel[m]
-                new_mu = start_col[m] + dur_col[m]
-                mus[pos] = new_mu
-                pid_col[m] = ids[pos]
-                if persistent:
-                    touched.setdefault(int(c), []).append(pid_col[m].copy())
-                min_new[int(c)] = min(min_new[int(c)], float(new_mu.min()))
-            for i in np.flatnonzero(readj_col[pos0:cut]).tolist():
-                i += pos0
-                pending.append((base + i, int(gidx[i]), float(dur_col[i]),
-                                int(prim[i])))
-            return cut - pos0
 
         def place_one(i: int):
-            """The scalar rule for one violating task, over the same pools
-            (argmin over a pool's contiguous mu column is worst-fit with
-            the identical lowest-pair-id tie-break)."""
+            """The per-task rule over the pools (argmin over a pool's
+            contiguous mu column is worst-fit with the identical
+            lowest-pair-id tie-break)."""
             g = int(gidx[i])
             dd = d[i]
-            readj_col[i] = False  # may hold a stale beyond-cut batch verdict
             for c in (order_cols[g] if order_cols is not None else (0,)):
                 ids, mus, n = pool(c)
                 if not n:
@@ -855,10 +571,6 @@ class PlacementContext:
                 th = t_hat_l[c][g]
                 if dd - start >= th - _EPS:
                     mus[j] = start + th
-                    if persistent:
-                        touched.setdefault(c, []).append(int(ids[j]))
-                    if min_new[c] > start + th:
-                        min_new[c] = start + th
                     pid_col[i], start_col[i], dur_col[i], cls_col[i] = \
                         ids[j], start, th, c
                     return
@@ -870,10 +582,6 @@ class PlacementContext:
                     window = dd - start
                     if window >= t_theta - _EPS:
                         mus[j] = start + window
-                        if persistent:
-                            touched.setdefault(c, []).append(int(ids[j]))
-                        if min_new[c] > start + window:
-                            min_new[c] = start + window
                         pending.append((base + i, g, window, c))
                         pid_col[i], start_col[i], dur_col[i], cls_col[i] = \
                             ids[j], start, window, c
@@ -881,70 +589,44 @@ class PlacementContext:
                         return
             gp.acquire(i, g, int(prim[i]))
 
-        def finish_scalar(i0: int):
-            """The scalar rule for the rest of the group as a tight loop
-            over a lazy frontier heap: alive candidate-stream originals,
-            pairs already assigned this group, and outstanding fresh pairs,
-            keyed ``(mu, pair id)`` — exactly argmin's lowest-pair-id
-            tie-break.  Entries go stale by exact ``mu`` comparison; when
-            the original stream runs dry while uncovered pool entries
-            exist, the loop degrades to plain argmin over the pool.
-            Per-task reads come off plain python lists and the record
-            columns are written back in bulk.  Multi-class groups fall back
-            to the per-task rule, which also handles class fallback."""
-            if order_cols is not None:
-                for j in range(i0, k):
-                    place_one(j)
-                return
+        def finish_scalar():
+            """The per-task rule for a single-class online group as a tight
+            loop over a frontier heap keyed ``(mu, pair id)`` — exactly
+            argmin's lowest-pair-id tie-break — whose entries carry their
+            pool position.  The heap is seeded with the ``k`` smallest pool
+            entries: every entry left out compares greater and keeps its
+            ``mu`` until a task takes it, and each task takes at most one
+            heap entry, so a seed entry is always left and the heap top is
+            the pool's argmin for every task.  A power-on pushes its fresh
+            pairs.  Per-task reads come off plain python lists and the
+            record columns are written back in bulk."""
             gl = gidx.tolist()
             dl = d.tolist()
             th_l = t_hat_l[0]
             tm_l = t_min_l[0]
             pid_l, st_l, du_l, rj_l = [], [], [], []
             ids, mus, n = pool(0)
-            cp, cm = candidates(0, k - i0)
-            heap = [(m, int(ids[p]), int(p), True)
-                    for m, p in zip(cm.tolist(), cp.tolist())]
-            alive_orig = len(heap)
-            statics = alive_orig < n              # uncovered pool entries?
-            if i0:
-                tpos = np.unique(np.searchsorted(ids[:n], pid_col[:i0]))
-                heap += [(float(mus[p]), int(ids[p]), int(p), False)
-                         for p in tpos.tolist()]
-            for p in fresh.get(0, ()):
-                if mus[p] == t_now:
-                    heap.append((t_now, int(ids[p]), int(p), False))
+            m_live = mus[:n]
+            if k < n:
+                part = np.argpartition(m_live, k - 1)[:k]
+                cp = np.flatnonzero(m_live <= m_live[part].max())
+                cp = cp[np.lexsort((cp, m_live[cp]))][:k]
+            else:
+                cp = np.arange(n)
+            heap = list(zip(m_live[cp].tolist(), ids[cp].tolist(),
+                            cp.tolist()))
             heapq.heapify(heap)
-            heap_ok = True
-            for j in range(i0, k):
+            heappush = heapq.heappush
+            heapreplace = heapq.heapreplace
+            for j in range(k):
                 g = gl[j]
                 dd = dl[j]
-                top = None
-                if heap_ok:
-                    while heap:
-                        e = heap[0]
-                        if mus[e[2]] == e[0]:
-                            top = e
-                            break
-                        heapq.heappop(heap)
-                        if e[3]:
-                            alive_orig -= 1
-                    if top is None or (statics and alive_orig == 0):
-                        heap_ok = False
-                        top = None
-                if not heap_ok and n:
-                    p = int(mus[:n].argmin())
-                    top = (float(mus[p]), int(ids[p]), p, False)
-                if top is not None:
-                    mu_p, pid, p = top[0], top[1], top[2]
+                if heap:
+                    mu_p, pid, p = heap[0]
                     start = t_now if mu_p < t_now else mu_p
                     th = th_l[g]
                     if dd - start >= th - _EPS:
-                        if heap_ok:
-                            heapq.heappop(heap)
-                            if top[3]:
-                                alive_orig -= 1
-                            heapq.heappush(heap, (start + th, pid, p, False))
+                        heapreplace(heap, (start + th, pid, p))
                         mus[p] = start + th
                         pid_l.append(pid)
                         st_l.append(start)
@@ -958,13 +640,7 @@ class PlacementContext:
                             t_theta = t_mn
                         window = dd - start
                         if window >= t_theta - _EPS:
-                            if heap_ok:
-                                heapq.heappop(heap)
-                                if top[3]:
-                                    alive_orig -= 1
-                                heapq.heappush(heap,
-                                               (start + window, pid, p,
-                                                False))
+                            heapreplace(heap, (start + window, pid, p))
                             mus[p] = start + window
                             pending.append((base + j, g, window, 0))
                             pid_l.append(pid)
@@ -973,43 +649,38 @@ class PlacementContext:
                             rj_l.append(True)
                             continue
                 pos, mid = gp.acquire(j, g, 0)
-                ids, mus, n = pools[0]
-                if heap_ok:
-                    if mid:
-                        # positions past the insertion point shifted
-                        heap = [(m_, pi_, p_ + grain if p_ >= pos else p_,
-                                 o_) for m_, pi_, p_, o_ in heap]
-                    npid = int(ids[pos])
-                    heapq.heappush(heap, (float(mus[pos]), npid, pos, False))
-                    for jj in range(1, grain):
-                        heapq.heappush(heap,
-                                       (t_now, npid + jj, pos + jj, False))
+                ids, mus, _ = gp.pools[0]
+                if mid:
+                    # positions past the insertion point shifted; the keys
+                    # did not, so the list is still a heap
+                    heap = [(m_, pi_, p_ + grain if p_ >= pos else p_)
+                            for m_, pi_, p_ in heap]
+                npid = int(ids[pos])
+                heappush(heap, (float(mus[pos]), npid, pos))
+                for jj in range(1, grain):
+                    heappush(heap, (t_now, npid + jj, pos + jj))
                 pid_l.append(pid_col[j])
                 st_l.append(t_now)
                 du_l.append(dur_col[j])
                 rj_l.append(False)
-            pid_col[i0:] = pid_l
-            start_col[i0:] = st_l
-            dur_col[i0:] = du_l
-            readj_col[i0:] = rj_l
-            if persistent and pid_l:
-                touched.setdefault(0, []).append(
-                    np.asarray(pid_l, dtype=np.int64))
+            pid_col[:] = pid_l
+            start_col[:] = st_l
+            dur_col[:] = du_l
+            readj_col[:] = rj_l
 
-        def finish_offline(i0: int):
-            """The offline (single-class, ``grain == 1``) specialization of
-            :func:`finish_scalar`: the scalar worst-fit rule as a frontier
-            heap over plain python floats.
+        def finish_offline():
+            """The offline (single-class, ``grain == 1``) heap finisher: the
+            per-task worst-fit rule as a frontier heap over plain python
+            floats.
 
             With no power-on granule and no eligibility churn the WHOLE
-            pool fits in the heap (so no lazy-staleness or argmin-degrade
-            machinery is needed — a ``(mu, pair id)`` heap top IS argmin's
+            pool fits in the heap (a ``(mu, pair id)`` heap top IS argmin's
             lowest-pair-id tie-break, and every mutation is a
             ``heapreplace`` of the top), and fresh pairs are deferred to
             ONE bulk :meth:`~repro.core.engine.ClusterEngine.open_pairs` —
             offline pair ids are sequential, so they are known without
             touching the engine inside the loop.  Bit-identical to the
-            scalar rule by construction: the list mirrors hold the exact
+            per-task rule by construction: the list mirrors hold the exact
             float64 values of the pool columns."""
             eng = self.eng
             ids_a, mus_a, n = pool(0)
@@ -1024,7 +695,7 @@ class PlacementContext:
             heapreplace = heapq.heapreplace
             pid_next = eng.n_pairs
             n_fresh = 0
-            for j in range(i0, k):
+            for j in range(k):
                 g = gl[j]
                 dd = dl[j]
                 if heap:
@@ -1065,39 +736,20 @@ class PlacementContext:
                 rj_l.append(False)
             if n_fresh:
                 eng.open_pairs(np.zeros(n_fresh, dtype=np.int64))
-            pid_col[i0:] = pid_l
-            start_col[i0:] = st_l
-            dur_col[i0:] = du_l
-            readj_col[i0:] = rj_l
+            pid_col[:] = pid_l
+            start_col[:] = st_l
+            dur_col[:] = du_l
+            readj_col[:] = rj_l
 
-        # A group below the crossover (_SMALL_GROUP) takes the scalar rule
-        # task by task.  Otherwise alternate batch rounds with single scalar
-        # violators while batching pays for itself; a round that nets only
-        # a few tasks (power-on ramp, saturated frontier) costs more than
-        # the scalar rule, so finish the group scalar from there.
-        finish = finish_offline if (grain == 1 and not self.eng.server_mode
-                                    and order_cols is None) else finish_scalar
-        i = 0
-        batched = 0
-        n0 = pool(int(prim[0]))[2]
-        if k * (_SMALL_GROUP_POOL + n0) < _SMALL_GROUP * _SMALL_GROUP_POOL:
+        if order_cols is not None or k * (_SMALL_GROUP_POOL + pool(0)[2]) \
+                < _SMALL_GROUP * _SMALL_GROUP_POOL:
             for j in range(k):
                 place_one(j)
-            i = k
-        while i < k:
-            consumed = batch_round(i)
-            i += consumed
-            batched += consumed
-            if i >= k:
-                break
-            place_one(i)
-            i += 1
-            if consumed < 8:
-                if i < k:
-                    finish(i)
-                break
-        obs.count("placement.batched", batched)
-        obs.count("placement.scalar", k - batched)
+        elif self.eng.server_mode:
+            finish_scalar()
+        else:
+            finish_offline()
+        obs.count("placement.scalar", k)
 
         self._commit_group(gidx, pid_col, start_col, dur_col, readj_col,
                            cls_col)
@@ -1133,8 +785,6 @@ class PlacementContext:
         cls_col = np.empty(k, dtype=np.int64)
         gp = self._group_pools(t_now, pid_col, start_col, dur_col, cls_col)
         pool = gp.pool
-        persistent = gp.persistent
-        touched = gp.touched
 
         for i in range(k):
             g = gl[i]
@@ -1158,8 +808,6 @@ class PlacementContext:
                         continue
                 start = float(starts[j])
                 mus[j] = start + th
-                if persistent:
-                    touched.setdefault(c, []).append(int(ids[j]))
                 pid_col[i] = ids[j]
                 start_col[i] = start
                 dur_col[i] = th

@@ -33,8 +33,8 @@ All offline algorithms share the same three-phase structure:
    (min-energy feasible) class.
 
    The offline batch is the placement subsystem's degenerate "one group at
-   ``t = 0``" case: ``placement="vector"`` (default) runs the batched
-   worst-fit frontier / pooled probes of
+   ``t = 0``" case: ``placement="vector"`` (default) runs the worst-fit
+   heap finisher / pooled probes of
    :class:`~repro.core.placement.PlacementContext`,
    ``placement="scalar"`` the per-task reference loop over the engine
    selectors — bit-identical by construction
@@ -208,7 +208,7 @@ def schedule_offline(task_set: TaskSet, l: int = 1, theta: float = 1.0,
     precomputed single-class Algorithm-1 output) is only valid for the
     homogeneous case; ``cfgs`` injects the full per-class
     :func:`configure_all` output (must match ``task_set``/``classes``/
-    ``use_dvfs``/``interval``).  ``placement`` picks the batched array path
+    ``use_dvfs``/``interval``).  ``placement`` picks the pooled array path
     (``"vector"``, default) or the per-task reference loop (``"scalar"``);
     both produce bit-identical schedules.  ``bound=False`` skips the
     ``e_bound`` solve (benchmarks timing the packing hot path).

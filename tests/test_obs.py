@@ -142,7 +142,7 @@ def test_offline_counters_are_pinned():
     assert pinned(r.counters) == {
         "tasks": 602, "solve.rows": 1056, "solve.hits": 0,
         "solve.misses": 631, "solve.evictions": 0, "solve.sent": 1536,
-        "solve.pad": 905, "placement.batched": 0, "placement.scalar": 512,
+        "solve.pad": 905, "placement.scalar": 512,
         "placement.pinned": 90, "placement.fallback": 0}
     # a warm rerun: every row a hit, nothing sent
     r2 = scheduling.schedule_offline(ts, **KW)
@@ -158,7 +158,7 @@ def test_online_counters_are_pinned():
     assert pinned(r.counters) == {
         "tasks": 802, "solve.rows": 819, "solve.hits": 0,
         "solve.misses": 819, "solve.evictions": 0, "solve.sent": 1536,
-        "solve.pad": 717, "placement.batched": 0, "placement.scalar": 802,
+        "solve.pad": 717, "placement.scalar": 802,
         "placement.pinned": 0, "placement.fallback": 0}
 
 
@@ -176,10 +176,8 @@ def test_counters_add_up(entry, placement, algorithm):
         r = online.schedule_online(online_set(), placement=placement,
                                    algorithm=algorithm, **KW)
     c = r.counters
-    assert c["placement.batched"] + c["placement.scalar"] \
-        + c["placement.pinned"] == c["tasks"] == len(r.assignments)
-    if placement == "scalar" or algorithm == "bin":
-        assert c["placement.batched"] == 0
+    assert c["placement.scalar"] + c["placement.pinned"] \
+        == c["tasks"] == len(r.assignments)
     assert c["solve.pad"] == c["solve.sent"] - c["solve.misses"]
     assert c["solve.rows"] >= c["solve.hits"] + c["solve.misses"]
     assert r.cache_stats["hits"] == c["solve.hits"]

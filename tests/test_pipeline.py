@@ -144,24 +144,18 @@ def _stub_pools(grain=2):
     ctx = types.SimpleNamespace(eng=eng, grain=grain,
                                 pre={"t_hat_l": None})
     gp = placement._GroupPools(ctx, 0.0, None, None, None, None)
-    gp.persistent = True
     return eng, gp
 
 
 def test_epoch_bump_invalidates_carried_pools():
-    """A fault transition (pool_epoch bump) drops every carried pool and
-    stream — the next group rebuilds lazily from the live engine."""
+    """A fault transition (pool_epoch bump) drops every carried pool —
+    the next group rebuilds lazily from the live engine."""
     eng, gp = _stub_pools()
     gp.pools[0] = [np.arange(6, dtype=np.int64), np.zeros(6), 6]
-    gp.cands[0] = [np.arange(3), np.zeros(3)]
-    gp.min_new[0] = 1.0
-    gp.thresh[0] = (0.0, 0)
-    gp.needs_merge = {0}
     eng.pool_epoch += 1
     gp.begin_group(1.0, None, None, None, None)
     assert gp.epoch == eng.pool_epoch
-    assert not gp.pools and not gp.cands and not gp.min_new
-    assert not gp.thresh and not gp.needs_merge
+    assert not gp.pools
     assert eng.drains == 1              # queued power-offs still consumed
 
 
@@ -174,22 +168,17 @@ def test_same_epoch_keeps_carried_pools():
 
 
 def test_power_off_deletion_compacts_pool_and_stream():
-    """Batched power-off: one keep-mask compaction per class; surviving
-    stream entries shift left by the deletions before them."""
+    """Batched power-off: one keep-mask compaction per class that keeps
+    the surviving pairs in id order with their own mu."""
     eng, gp = _stub_pools(grain=2)      # pair id = 2 * server + k
     ids = np.arange(8, dtype=np.int64)  # servers 0..3, fully pooled
     mus = np.arange(8, dtype=np.float64)
     gp.pools[0] = [ids.copy(), mus.copy(), 8]
-    gp.cands[0] = [np.array([1, 3, 6]), mus[[1, 3, 6]].copy()]
     gp.apply_offs([1])                  # cuts pair ids 2 and 3
     ids2, mus2, n2 = gp.pools[0]
     assert n2 == 6
     assert list(ids2[:n2]) == [0, 1, 4, 5, 6, 7]
     assert list(mus2[:n2]) == [0.0, 1.0, 4.0, 5.0, 6.0, 7.0]
-    cp, cm = gp.cands[0]
-    assert list(cp) == [1, 4]           # id 3 dropped; id 6 shifted by 2
-    assert list(cm) == [1.0, 6.0]
-    assert list(ids2[cp]) == [1, 6]     # positions still point at their ids
 
 
 def test_power_off_of_unpooled_server_is_a_noop():

@@ -15,6 +15,8 @@ online simulator uses.  These tests pin
   and the e_bound reporting contract of both schedulers.
 """
 
+import heapq
+
 import numpy as np
 import pytest
 
@@ -105,12 +107,26 @@ def _group_sizes(kind):
 def _sized_day(sizes, seed=5, gap=150.0):
     """Tasks drawn the §5.1.3 way, one arrival group of each size every
     ``gap`` slots (long enough that earlier tasks free pairs of servers
-    still on, so batched rounds find room), each task keeping its drawn
-    window."""
+    still on, so a group meets a non-empty pool), each task keeping its
+    drawn window."""
     ts = tasks.generate_offline_n(sum(sizes), seed=seed)
     arrival = np.repeat(gap * np.arange(1.0, len(sizes) + 1.0), sizes)
     return tasks.TaskSet(arrival, arrival + ts.deadline, ts.params,
                          ts.utilization)
+
+
+class _CountingHeapq:
+    """``heapq`` with its ``heapify`` calls counted: the heap finisher
+    heapifies its seed once per group and the per-task rule never does."""
+
+    def __init__(self):
+        self.heapify_calls = 0
+        self.heappush = heapq.heappush
+        self.heapreplace = heapq.heapreplace
+
+    def heapify(self, heap):
+        self.heapify_calls += 1
+        heapq.heapify(heap)
 
 
 @pytest.mark.parametrize("faults", [False, True])
@@ -118,7 +134,8 @@ def _sized_day(sizes, seed=5, gap=150.0):
 @pytest.mark.parametrize("mix", sorted(MIXES))
 @pytest.mark.parametrize("sizes", ["below", "at", "above", "mix"])
 def test_online_vector_bit_identical_around_small_groups(sizes, mix,
-                                                         pipeline, faults):
+                                                         pipeline, faults,
+                                                         monkeypatch):
     ts = _sized_day(_group_sizes(sizes))
     kw = dict(l=2, theta=0.9, algorithm="edl", classes=MIXES[mix],
               pipeline=pipeline, bound=False)
@@ -127,6 +144,8 @@ def test_online_vector_bit_identical_around_small_groups(sizes, mix,
             tasks.peak_pair_estimate(ts) // 2, float(ts.arrival[-1]),
             mtbf=400.0, mttr=50.0, seed=1)
     r_s = online.schedule_online(ts, placement="scalar", **kw)
+    heap = _CountingHeapq()
+    monkeypatch.setattr(placement, "heapq", heap)
     r_v = online.schedule_online(ts, placement="vector", **kw)
     assert r_v.e_total == r_s.e_total           # bit-for-bit
     assert (r_v.e_idle, r_v.e_overhead, r_v.violations, r_v.n_pairs) == \
@@ -136,15 +155,18 @@ def test_online_vector_bit_identical_around_small_groups(sizes, mix,
         assert r_v.fault_stats["failures"] > 0
     assert [_fields(a) for a in r_v.assignments] == \
         [_fields(a) for a in r_s.assignments]
-    batched = r_v.counters["placement.batched"]
+    # every task by a per-task rule, re-placed orphans once more
+    assert r_v.counters["placement.scalar"] == r_v.counters["tasks"] \
+        + (r_v.fault_stats or {}).get("restarted", 0)
     if sizes == "below":
         # no pool of the day holds enough pairs to move the crossover
         assert (placement._SMALL_GROUP - 1) * (
             placement._SMALL_GROUP_POOL + r_v.n_pairs) \
             < placement._SMALL_GROUP * placement._SMALL_GROUP_POOL
-        assert batched == 0
+    if sizes == "below" or mix == "mixed":
+        assert heap.heapify_calls == 0          # every task by place_one
     elif sizes in ("at", "above"):
-        assert batched > 0
+        assert heap.heapify_calls > 0           # the heap finisher ran
 
 
 # ---------------------------------------------------------------------------
